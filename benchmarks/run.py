@@ -87,6 +87,9 @@ def _write_serving_json(path: pathlib.Path, results: dict) -> None:
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", nargs="*", default=None)
     ap.add_argument(

@@ -82,6 +82,7 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent))
 
 from common import bench_config, csv_line  # noqa: E402
 
+from repro.kernels.core import pallas_interpret  # noqa: E402
 from repro.launch.serve import poisson_trace  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.serving import FedAttnEngine, NGramDrafter  # noqa: E402
@@ -757,7 +758,7 @@ def _paged_flash_pass(args):
     speedup, wall_gather, wall_fused = rounds[len(rounds) // 2]
     tok_s = {"gather": total_new / wall_gather, "fused": total_new / wall_fused}
     n_decode = pools["pallas"]["sched"].compile_counts["decode_step"]
-    interpret = jax.default_backend() != "tpu"
+    interpret = pallas_interpret()
     name = "serving_paged_flash"
     print(csv_line(name, 1e6 / tok_s["fused"],
                    f"tok_s={tok_s['fused']:.1f},vs_gather={speedup:.2f}x,"

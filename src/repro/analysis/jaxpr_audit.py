@@ -70,6 +70,7 @@ from dataclasses import dataclass
 from typing import Callable, Iterable, Optional
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
@@ -109,15 +110,15 @@ class EntryPoint:
 
 def _subjaxprs(params: dict):
     for v in params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
         elif isinstance(v, (list, tuple)):
             for item in v:
-                if isinstance(item, jax.core.ClosedJaxpr):
+                if isinstance(item, jex_core.ClosedJaxpr):
                     yield item.jaxpr
-                elif isinstance(item, jax.core.Jaxpr):
+                elif isinstance(item, jex_core.Jaxpr):
                     yield item
 
 
@@ -144,6 +145,17 @@ def _avals(jaxpr):
 # ---------------------------------------------------------------------------
 
 
+def donated_argnums(traced) -> tuple:
+    """Positional arguments of a ``jax.stages.Traced`` that donate any of
+    their buffers. (``Traced.donate_argnums`` counts flattened leaves, so a
+    donated cache pytree shows up as a run of leaf indices, not as its
+    argument position.)"""
+    return tuple(
+        i for i, arg in enumerate(traced.args_info[0])
+        if any(leaf.donated for leaf in jax.tree.leaves(arg))
+    )
+
+
 def audit_traced(
     name: str,
     traced,
@@ -151,8 +163,8 @@ def audit_traced(
     donate_expected: Optional[tuple] = None,
     max_const_bytes: int = MAX_CONST_BYTES,
 ) -> list[AuditIssue]:
-    """Audit one ``jax.stages.Traced`` (or anything with ``.jaxpr`` /
-    ``.donate_argnums``) against the static serving contracts."""
+    """Audit one ``jax.stages.Traced`` against the static serving
+    contracts."""
     issues: list[AuditIssue] = []
     closed = traced.jaxpr  # ClosedJaxpr
     jaxpr = closed.jaxpr
@@ -183,7 +195,7 @@ def audit_traced(
 
     # -- donation ----------------------------------------------------------
     if donate_expected is not None:
-        declared = tuple(sorted(getattr(traced, "donate_argnums", ()) or ()))
+        declared = donated_argnums(traced)
         expected = tuple(sorted(donate_expected))
         if declared != expected:
             issues.append(AuditIssue(

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.distributed import runtime
 from repro.kernels import core as K
 

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.distributed import runtime
 from repro.models import moe as M
 from repro.types import ModelConfig

@@ -2,6 +2,6 @@
 
 Each kernel ships three artifacts:
   <name>.py  pl.pallas_call + BlockSpec implementation (TPU target)
-  ops.py     jit'd public wrappers with shape checks + interpret fallback
+  ops.py     public wrappers with backend dispatch
   ref.py     pure-jnp oracles used for validation and as CPU fallback
 """

@@ -251,28 +251,65 @@ def visibility(
       eq. 18); otherwise ``contributed`` thins the off-diagonal to the
       exchanged rows (Phase II, eqs. 20-21 / 37).
     """
-    qp, kp = _as2(q_pos), _as2(kv_pos)
+    col = lambda a: None if a is None else _as2(a)[:, :, None]
+    row = lambda a: None if a is None else _as2(a)[:, None, :]
+    if publisher_lo is not None:
+        publisher_lo = jnp.asarray(publisher_lo).reshape((-1, 1, 1))
+    use_seg = q_seg is not None and kv_seg is not None
+    return tile_visibility(
+        col(q_pos), row(kv_pos),
+        col(q_seg) if use_seg else None, row(kv_seg) if use_seg else None,
+        causal=causal, local_only=local_only, contributed=row(contributed),
+        window=window, publisher_lo=publisher_lo,
+    )
+
+
+def tile_visibility(
+    q_pos: jnp.ndarray,  # (..., Lq, 1) query column
+    kv_pos: jnp.ndarray,  # (..., 1, Lk) key row
+    q_seg: Optional[jnp.ndarray] = None,
+    kv_seg: Optional[jnp.ndarray] = None,
+    *,
+    causal: bool = True,
+    local_only: bool = False,
+    contributed: Optional[jnp.ndarray] = None,  # (..., 1, Lk)
+    window: Optional[int] = None,
+    publisher_lo=None,  # static int or an array broadcastable to the mask
+) -> jnp.ndarray:
+    """The rule body of :func:`visibility` on operands that are already
+    broadcast-shaped: query-side vectors as columns, key-side vectors as
+    rows. The mask is their ordinary broadcast — a compiled Pallas kernel
+    calls this on its ``(rows, 1)`` / ``(1, cols)`` tiles (2-D, no
+    lane-to-sublane relayout), :func:`visibility` on ``(B, L, 1)`` /
+    ``(B, 1, L)`` views of the vector contract."""
     if causal:
-        mask = qp[:, :, None] >= kp[:, None, :]
+        mask = q_pos >= kv_pos
     else:
         mask = jnp.broadcast_to(
-            kp[:, None, :] < PAD_POS,
-            (max(qp.shape[0], kp.shape[0]), qp.shape[1], kp.shape[1]),
+            kv_pos < PAD_POS, jnp.broadcast_shapes(q_pos.shape, kv_pos.shape)
         )
     if window is not None:
-        mask &= (qp[:, :, None] - kp[:, None, :]) < window
+        mask &= (q_pos - kv_pos) < window
     if publisher_lo is not None:
-        lo = jnp.asarray(publisher_lo).reshape((-1, 1, 1))  # scalar or (B,)
-        mask &= kp[:, None, :] >= lo
+        mask &= kv_pos >= publisher_lo
     if q_seg is not None and kv_seg is not None:
-        qs, ks = _as2(q_seg), _as2(kv_seg)
-        mask &= ks[:, None, :] >= 0
-        same = qs[:, :, None] == ks[:, None, :]
+        mask &= kv_seg >= 0
+        same = q_seg == kv_seg
         if local_only:
             mask &= same
         elif contributed is not None:
-            mask &= same | _as2(contributed)[:, None, :]
+            mask &= same | contributed
     return mask
+
+
+def pallas_interpret(interpret: Optional[bool] = None) -> bool:
+    """THE interpret rule of every Pallas kernel in the repo: compiled on a
+    TPU, run by the JAX interpreter anywhere else (CPU tests). An explicit
+    ``interpret`` wins — a test compiling for a described TPU from a CPU
+    process passes ``False``."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
 
 
 @dataclass(frozen=True)

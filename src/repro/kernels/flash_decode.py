@@ -6,14 +6,17 @@ groups) before the shared softmax body ran — and quantized pools
 dequantized *outside* the kernel, spending part of the memory traffic the
 int8/fp8 codes saved. This module fuses the whole read side:
 
-* **In-kernel page loads.** The grid is ``(B, nq, P')`` — one program per
-  (slot, query head, page-table entry). The page table rides in as a
-  scalar-prefetch operand and the K/V BlockSpec *index maps* resolve each
-  program's physical page (``min(table[b, i], N-1)``), so the kernel reads
-  page blocks straight from the ``(num_pages, page_size, nkv, dh)`` pool.
-  The dense ``(B, capacity)`` cache is never materialized — the fused
-  jaxpr contains no full-pool gather (audited:
-  ``analysis.jaxpr_audit.audit_fused_decode``).
+* **In-kernel page loads.** The grid is ``(B, nkv, P')`` — one program per
+  (slot, kv head, page-table entry); the ``g`` query heads of a GQA group
+  and the ``S`` query rows share one program as ``g·S`` rows of a single
+  tile. The page table rides in as a scalar-prefetch operand and the K/V
+  BlockSpec *index maps* resolve each program's physical page
+  (``min(table[b, i], N-1)``), reading the ``(page_size, dh)`` block of
+  kv head ``h`` straight from the pool viewed as ``(num_pages,
+  page_size, nkv·dh)`` (a free reshape — every block keeps whole
+  trailing dims, as the TPU's tiling requires). The dense ``(B,
+  capacity)`` cache is never materialized — the fused jaxpr contains no
+  full-pool gather (audited: ``analysis.jaxpr_audit.audit_fused_decode``).
 * **Split-KV flash-decoding.** Each program emits partial ``(m, l, acc)``
   softmax stats in the exact ``kernels.core.masked_attention(
   return_stats=True)`` vocabulary; :func:`_finish` reduces them with the
@@ -45,8 +48,9 @@ operands and share :func:`_finish`). One exception: under ``soft_cap`` the
 backend's ``tanh`` wobbles at 1 ulp with vectorization shape, so
 soft-capped parity is to f32 rounding rather than bitwise.
 
-``interpret=None`` auto-selects: ``True`` off-TPU (CI runs the kernel body
-under the JAX interpreter — bitwise-testable on CPU), ``False`` on TPU.
+``interpret=None`` follows :func:`repro.kernels.core.pallas_interpret`:
+compiled on a TPU, the JAX interpreter elsewhere (CI runs the kernel body
+interpreted — bitwise-testable on CPU).
 
 Mass (the ``'attnmass'`` wiring): with ``return_mass`` the kernel also
 emits each page block's masked softmax numerators; :func:`_finish`
@@ -70,61 +74,87 @@ from repro.kernels import core as _core
 
 
 def _block_attend(q, k, v, mask, soft_cap):
-    """Attention stats of one (query block × KV page block) tile.
+    """Attention stats of one (query rows × KV page block) tile.
 
-    ``q`` (S, dh) **pre-scaled** f32, ``k``/``v`` (ps, dh) f32 (already
-    dequantized), ``mask`` (S, ps) bool. Returns ``(m, l, acc, p)`` —
-    ``m``/``l`` (S,), ``acc`` (S, dh), ``p`` (S, ps) the masked softmax
-    numerators relative to ``m``. The ONE tile body: the Pallas kernel and
-    the XLA ref twin both run exactly this function, which is what makes
-    their parity bitwise. Fully-masked rows follow the core contract
+    ``q`` (R, dh) **pre-scaled** f32 — the ``R = g·S`` query rows of one
+    GQA group, ``k``/``v`` (ps, dh) f32 (already dequantized), ``mask``
+    (R, ps) bool. Returns ``(m, l, acc, p)`` — ``m``/``l`` (R, 1) columns,
+    ``acc`` (R, dh), ``p`` (R, ps) the masked softmax numerators relative
+    to ``m``. The ONE tile body: the Pallas kernel and the XLA ref twin
+    both run exactly this function on identical operands, which is what
+    makes their parity bitwise. Fully-masked rows follow the core contract
     (masked_attention): ``p`` is re-masked to zero, so they contribute
     ``l = 0`` and combine to zero output, never NaN."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (S, ps)
+    )  # (R, ps)
     if soft_cap:
         s = jnp.tanh(s / soft_cap) * soft_cap
     s = jnp.where(mask, s, _core.NEG_INF)
-    m = jnp.max(s, axis=-1)  # (S,)
-    p = jnp.where(mask, jnp.exp(s - m[:, None]), 0.0)  # (S, ps)
-    l = jnp.sum(p, axis=-1)  # (S,)
+    m = jnp.max(s, axis=-1, keepdims=True)  # (R, 1)
+    p = jnp.where(mask, jnp.exp(s - m), 0.0)  # (R, ps)
+    l = jnp.sum(p, axis=-1, keepdims=True)  # (R, 1)
     acc = jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    )  # (S, dh)
+    )  # (R, dh)
     return m, l, acc, p
 
 
 def _prep(q, pk, pages, q_pos, kv_pos, q_seg, kv_seg, contributed, local_only):
-    """Shared operand pre-pass: broadcast the position/segment vectors to
-    the 2-D per-row form and force sentinel-page columns (table entries
-    >= num_pages) behind the ``PAD_POS``/``KERNEL_PAD_SEGMENT`` sentinels
-    BEFORE any visibility decision — gathers/block loads clamp, masks hide
-    (the kernels.core paged contract)."""
-    B, S = q.shape[:2]
-    N, ps = pk.shape[0], pk.shape[1]
-    Lk = pages.shape[1] * ps
+    """Shared operand pre-pass, in the tile layout both implementations
+    read. Queries are grouped by kv head: ``qg`` (B, nkv, g·S, dh) with
+    row ``j·S + s`` = q head ``h·g + j`` at query row ``s`` (exactly
+    ``jnp.repeat`` GQA semantics). Query-side vectors become per-group
+    columns ``(B, g·S, 1)``; key-side vectors become per-page rows
+    ``(B, P', 1, ps)``, with sentinel-page columns (table entries >=
+    num_pages) forced behind the ``PAD_POS``/``KERNEL_PAD_SEGMENT``
+    sentinels BEFORE any visibility decision — gathers/block loads clamp,
+    masks hide (the kernels.core paged contract). Every block of these
+    layouts has whole trailing dims, as the TPU's tiling requires."""
+    B, S, nq, dh = q.shape
+    N, ps, nkv = pk.shape[0], pk.shape[1], pk.shape[2]
+    g = nq // nkv
+    Pp = pages.shape[1]
+    Lk = Pp * ps
     pages = pages.astype(jnp.int32)
+    qg = q.reshape(B, S, nkv, g, dh).transpose(0, 2, 3, 1, 4)
+    qg = qg.reshape(B, nkv, g * S, dh)
     col_valid = jnp.repeat(pages < N, ps, axis=1)  # (B, Lk)
-    qp = jnp.broadcast_to(jnp.atleast_2d(q_pos), (B, S))
-    kp = jnp.broadcast_to(jnp.atleast_2d(kv_pos), (B, Lk))
-    kp = jnp.where(col_valid, kp, _core.PAD_POS)
+    q_col = lambda a: jnp.tile(
+        jnp.broadcast_to(jnp.atleast_2d(a), (B, S)), (1, g)
+    )[:, :, None]
+    kv_row = lambda a, pad: jnp.where(
+        col_valid, jnp.broadcast_to(jnp.atleast_2d(a), (B, Lk)), pad
+    ).astype(jnp.int32).reshape(B, Pp, 1, ps)
+    qp = q_col(q_pos)
+    kp = kv_row(kv_pos, _core.PAD_POS)
     qs = ks = ct = None
     if q_seg is not None and kv_seg is not None:
-        qs = jnp.broadcast_to(jnp.atleast_2d(q_seg), (B, S))
-        ks = jnp.broadcast_to(jnp.atleast_2d(kv_seg), (B, Lk))
-        ks = jnp.where(col_valid, ks, _core.KERNEL_PAD_SEGMENT)
+        qs = q_col(q_seg)
+        ks = kv_row(kv_seg, _core.KERNEL_PAD_SEGMENT)
         if not local_only and contributed is not None:
-            ct = jnp.broadcast_to(jnp.atleast_2d(contributed), (B, Lk))
-    return pages, qp, kp, qs, ks, ct
+            # int32 0/1: bool blocks are fragile
+            ct = kv_row(jnp.asarray(contributed).astype(jnp.int32), 0)
+    return qg, pages, qp, kp, qs, ks, ct
 
 
-def _finish(q_dtype, m_p, l_p, acc_p, p_p, *, return_stats, return_mass):
+def _ungroup(x, S):
+    """(B, nkv, P', g·S, T) tile-layout stats → (B, nq, P', S, T)."""
+    B, nkv, Pp, R, T = x.shape
+    g = R // S
+    x = x.reshape(B, nkv, Pp, g, S, T).transpose(0, 1, 3, 2, 4, 5)
+    return x.reshape(B, nkv * g, Pp, S, T)
+
+
+def _finish(q_dtype, S, m_p, l_p, acc_p, p_p, *, return_stats, return_mass):
     """Combine per-page partial stats — THE split-KV reduction, in the
     exact stats vocabulary of ``core.masked_attention(return_stats=True)``
     / the spmd_attention pmax-psum combine: global max over page groups,
     exp-correction of each group's ``l``/``acc``, sum. Shared by the fused
-    kernel and the XLA ref twin (bitwise parity)."""
+    kernel and the XLA ref twin (bitwise parity); takes their tile-layout
+    outputs (B, nkv, P', g·S, ·)."""
+    m_p, l_p = _ungroup(m_p, S)[..., 0], _ungroup(l_p, S)[..., 0]
+    acc_p = _ungroup(acc_p, S)
     B, nq, Pp, S = m_p.shape
     m_g = jnp.max(m_p, axis=2)  # (B, nq, S)
     corr = jnp.exp(m_p - m_g[:, :, None, :])  # (B, nq, P', S)
@@ -132,6 +162,7 @@ def _finish(q_dtype, m_p, l_p, acc_p, p_p, *, return_stats, return_mass):
     acc_g = jnp.sum(acc_p * corr[..., None], axis=2)  # (B, nq, S, dh)
     p_rel = None
     if p_p is not None:
+        p_p = _ungroup(p_p, S)
         ps = p_p.shape[-1]
         # numerators rebased to the combined max, page blocks → columns
         p_rel = (p_p * corr[..., None]).transpose(0, 1, 3, 2, 4).reshape(
@@ -178,69 +209,65 @@ def paged_flash_decode(
     Returns the normalized ``(B, S, nq, dh)`` output; with ``return_stats``
     the combinable ``(m, l, acc)`` stats instead (SPMD shard-local form);
     ``return_mass`` appends the per-column softmax mass ``(B, P'*ps)``
-    (stats form: the raw ``p_rel`` numerators ``(B, nq, S, P'*ps)``)."""
+    (stats form: the raw ``p_rel`` numerators ``(B, nq, S, P'*ps)``).
+    Compiled, the head dim must be a multiple of 128 (one lane block per
+    kv head)."""
     B, S, nq, dh = q.shape
     N, ps, nkv, _ = pk.shape
     Pp = pages.shape[1]
-    g = nq // nkv
+    R = (nq // nkv) * S
     scale = sm_scale if sm_scale is not None else dh**-0.5
     quant = k_scales is not None
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _core.pallas_interpret(interpret)
 
-    pages, qp, kp, qs, ks, ct = _prep(
+    qg, pages, qp, kp, qs, ks, ct = _prep(
         q, pk, pages, q_pos, kv_pos, q_seg, kv_seg, contributed, local_only
     )
     use_seg = qs is not None
     use_ct = ct is not None
 
-    # index maps: grid (b, h, pi) + the scalar-prefetched page table `pr`;
-    # they return BLOCK indices — the resolved (clamped) physical page for
-    # pool-shaped operands, GQA head h // g for the kv-head axis
+    # index maps: grid (b, h, pi) over (slot, kv head, page-table entry)
+    # + the scalar-prefetched page table `pr`; they return BLOCK indices —
+    # the resolved (clamped) physical page for pool-shaped operands. The
+    # pool is viewed as (N, ps, nkv·dh) so kv head h is lane block h.
     pg_of = lambda b, pi, pr: jnp.minimum(pr[b, pi], N - 1)
     pool_spec = pl.BlockSpec(
-        (1, ps, 1, dh), lambda b, h, pi, pr: (pg_of(b, pi, pr), 0, h // g, 0)
+        (1, ps, dh), lambda b, h, pi, pr: (pg_of(b, pi, pr), 0, h)
     )
-    row_q = pl.BlockSpec((1, S), lambda b, h, pi, pr: (b, 0))
-    row_kv = pl.BlockSpec((1, ps), lambda b, h, pi, pr: (b, pi))
+    q_rows = pl.BlockSpec((1, R, 1), lambda b, h, pi, pr: (b, 0, 0))
+    kv_cols = pl.BlockSpec((1, 1, 1, ps), lambda b, h, pi, pr: (b, pi, 0, 0))
 
     in_specs = [
-        pl.BlockSpec((1, S, 1, dh), lambda b, h, pi, pr: (b, 0, h, 0)),
+        pl.BlockSpec((1, 1, R, dh), lambda b, h, pi, pr: (b, h, 0, 0)),
         pool_spec,
         pool_spec,
     ]
-    operands = [q, pk, pv]
+    operands = [qg, pk.reshape(N, ps, nkv * dh), pv.reshape(N, ps, nkv * dh)]
     if quant:
         scale_spec = pl.BlockSpec(
-            (1, 1), lambda b, h, pi, pr: (pg_of(b, pi, pr), h // g)
+            (1, 1, 1, 1), lambda b, h, pi, pr: (pg_of(b, pi, pr), h, 0, 0)
         )
         in_specs += [scale_spec, scale_spec]
-        operands += [k_scales, v_scales]
-    in_specs += [row_q, row_kv]
+        operands += [k_scales.reshape(N, nkv, 1, 1),
+                     v_scales.reshape(N, nkv, 1, 1)]
+    in_specs += [q_rows, kv_cols]
     operands += [qp, kp]
     if use_seg:
-        in_specs += [row_q, row_kv]
+        in_specs += [q_rows, kv_cols]
         operands += [qs, ks]
     if use_ct:
-        in_specs += [row_kv]
-        operands += [ct.astype(jnp.int32)]  # bool blocks are fragile; != 0 below
+        in_specs += [kv_cols]
+        operands += [ct]
 
-    stat_spec = pl.BlockSpec((1, 1, 1, S), lambda b, h, pi, pr: (b, h, pi, 0))
-    out_specs = [
-        stat_spec,
-        stat_spec,
-        pl.BlockSpec((1, 1, 1, S, dh), lambda b, h, pi, pr: (b, h, pi, 0, 0)),
-    ]
-    out_shape = [
-        jax.ShapeDtypeStruct((B, nq, Pp, S), jnp.float32),
-        jax.ShapeDtypeStruct((B, nq, Pp, S), jnp.float32),
-        jax.ShapeDtypeStruct((B, nq, Pp, S, dh), jnp.float32),
-    ]
+    tile = lambda w: pl.BlockSpec(
+        (1, 1, 1, R, w), lambda b, h, pi, pr: (b, h, pi, 0, 0)
+    )
+    stat = lambda w: jax.ShapeDtypeStruct((B, nkv, Pp, R, w), jnp.float32)
+    out_specs = [tile(1), tile(1), tile(dh)]
+    out_shape = [stat(1), stat(1), stat(dh)]
     if return_mass:
-        out_specs += [
-            pl.BlockSpec((1, 1, 1, S, ps), lambda b, h, pi, pr: (b, h, pi, 0, 0))
-        ]
-        out_shape += [jax.ShapeDtypeStruct((B, nq, Pp, S, ps), jnp.float32)]
+        out_specs.append(tile(ps))
+        out_shape.append(stat(ps))
 
     def kernel(pages_ref, *refs):
         it = iter(refs)
@@ -254,40 +281,40 @@ def paged_flash_decode(
         m_ref, l_ref, acc_ref = next(it), next(it), next(it)
         mass_ref = next(it) if return_mass else None
 
-        qv = q_ref[...][0, :, 0, :].astype(jnp.float32) * scale  # (S, dh)
-        kv = k_ref[...][0, :, 0, :]  # (ps, dh) codes or dense
-        vv = v_ref[...][0, :, 0, :]
+        qv = q_ref[0, 0].astype(jnp.float32) * scale  # (R, dh)
+        kv = k_ref[0]  # (ps, dh) codes or dense
+        vv = v_ref[0]
         if quant:
             # dequant-at-load: the codec semantics live in serving/quant —
             # this kernel only applies the (already per-page-per-head
             # resolved) scale to its block
             from repro.serving import quant as _quant
 
-            kv = _quant.dequantize(kv, sk_ref[0, 0])
-            vv = _quant.dequantize(vv, sv_ref[0, 0])
+            kv = _quant.dequantize(kv, sk_ref[0, 0, 0])
+            vv = _quant.dequantize(vv, sv_ref[0, 0, 0])
         else:
             kv = kv.astype(jnp.float32)
             vv = vv.astype(jnp.float32)
-        mask = _core.visibility(
-            qp_ref[...], kp_ref[...],
-            qs_ref[...] if use_seg else None,
-            ks_ref[...] if use_seg else None,
+        mask = _core.tile_visibility(
+            qp_ref[0], kp_ref[0, 0],
+            qs_ref[0] if use_seg else None,
+            ks_ref[0, 0] if use_seg else None,
             causal=causal, local_only=local_only,
-            contributed=(ct_ref[...] != 0) if use_ct else None,
+            contributed=(ct_ref[0, 0] != 0) if use_ct else None,
             window=window, publisher_lo=publisher_lo,
-        )[0]  # (S, ps)
+        )  # (R, ps)
         m, l, acc, p = _block_attend(qv, kv, vv, mask, soft_cap)
-        m_ref[...] = m[None, None, None]
-        l_ref[...] = l[None, None, None]
-        acc_ref[...] = acc[None, None, None]
+        m_ref[0, 0, 0] = m
+        l_ref[0, 0, 0] = l
+        acc_ref[0, 0, 0] = acc
         if return_mass:
-            mass_ref[...] = p[None, None, None]
+            mass_ref[0, 0, 0] = p
 
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, nq, Pp),
+            grid=(B, nkv, Pp),
             in_specs=in_specs,
             out_specs=out_specs,
         ),
@@ -296,7 +323,7 @@ def paged_flash_decode(
     )(pages, *operands)
     p_p = outs[3] if return_mass else None
     return _finish(
-        q.dtype, outs[0], outs[1], outs[2], p_p,
+        q.dtype, S, outs[0], outs[1], outs[2], p_p,
         return_stats=return_stats, return_mass=return_mass,
     )
 
@@ -325,23 +352,25 @@ def paged_flash_decode_ref(
 ):
     """Pure-XLA twin of :func:`paged_flash_decode` with the IDENTICAL
     per-page partition and combine: gathers each table entry's (clamped)
-    page block, vmaps :func:`_block_attend` over (B, head, page) and
-    reduces through the shared :func:`_finish` — the bitwise parity target
-    for the interpret-mode kernel (tests/test_flash_decode.py)."""
+    page block, maps :func:`_block_attend` over every (B, kv head, page)
+    tile on the same grouped query rows and reduces through the shared
+    :func:`_finish` — the bitwise parity target for the interpret-mode
+    kernel (tests/test_flash_decode.py)."""
     B, S, nq, dh = q.shape
     N, ps, nkv, _ = pk.shape
-    Pp = pages.shape[1]
-    g = nq // nkv
     scale = sm_scale if sm_scale is not None else dh**-0.5
 
-    pages, qp, kp, qs, ks, ct = _prep(
+    qg, pages, qp, kp, qs, ks, ct = _prep(
         q, pk, pages, q_pos, kv_pos, q_seg, kv_seg, contributed, local_only
     )
-    mask = _core.visibility(
-        qp, kp, qs, ks, causal=causal, local_only=local_only,
-        contributed=ct, window=window, publisher_lo=publisher_lo,
-    )  # (B, S, Lk)
-    maskb = mask.reshape(B, S, Pp, ps).transpose(0, 2, 1, 3)  # (B, P', S, ps)
+    # (B, P', R, ps): query columns (B, 1, R, 1) against page rows
+    mask = _core.tile_visibility(
+        qp[:, None], kp,
+        qs[:, None] if qs is not None else None, ks,
+        causal=causal, local_only=local_only,
+        contributed=None if ct is None else ct != 0,
+        window=window, publisher_lo=publisher_lo,
+    )
 
     idx = jnp.minimum(pages, N - 1)
     kb = jnp.take(pk, idx, axis=0)  # (B, P', ps, nkv, dh)
@@ -354,18 +383,20 @@ def paged_flash_decode_ref(
     else:
         kb = kb.astype(jnp.float32)
         vb = vb.astype(jnp.float32)
-    # GQA: q head h reads kv head h // g — jnp.repeat semantics, exactly
-    # what the kernel's h // g block index map resolves
-    kh = jnp.repeat(kb, g, axis=3).transpose(0, 3, 1, 2, 4)  # (B, nq, P', ps, dh)
-    vh = jnp.repeat(vb, g, axis=3).transpose(0, 3, 1, 2, 4)
-    qh = (q.astype(jnp.float32) * scale).transpose(0, 2, 1, 3)  # (B, nq, S, dh)
-
-    tile = lambda q_, k_, v_, m_: _block_attend(q_, k_, v_, m_, soft_cap)
-    over_pages = jax.vmap(tile, in_axes=(None, 0, 0, 0))
-    over_heads = jax.vmap(over_pages, in_axes=(0, 0, 0, None))
-    over_batch = jax.vmap(over_heads, in_axes=(0, 0, 0, 0))
-    m_p, l_p, acc_p, p_p = over_batch(qh, kh, vh, maskb)
+    Pp, R = pages.shape[1], qg.shape[2]
+    # one (kv head, page) tile per map step, flattened over (B, nkv, P'):
+    # each step runs the kernel's unbatched tile body on identical operands
+    kt = kb.transpose(0, 3, 1, 2, 4).reshape(-1, ps, dh)
+    vt = vb.transpose(0, 3, 1, 2, 4).reshape(-1, ps, dh)
+    qt = jnp.broadcast_to(
+        (qg.astype(jnp.float32) * scale)[:, :, None], (B, nkv, Pp, R, dh)
+    ).reshape(-1, R, dh)
+    mt = jnp.broadcast_to(mask[:, None], (B, nkv, Pp, R, ps)).reshape(-1, R, ps)
+    stats = jax.lax.map(lambda t: _block_attend(*t, soft_cap), (qt, kt, vt, mt))
+    m_p, l_p, acc_p, p_p = (
+        x.reshape((B, nkv, Pp) + x.shape[1:]) for x in stats
+    )
     return _finish(
-        q.dtype, m_p, l_p, acc_p, p_p if return_mass else None,
+        q.dtype, S, m_p, l_p, acc_p, p_p if return_mass else None,
         return_stats=return_stats, return_mass=return_mass,
     )

@@ -16,7 +16,9 @@ hardware-adaptation note).
 
 Grid = (B, n_d_blocks, n_chunks), chunk axis LAST (sequential on TPU) so
 the state scratch carries across chunks. Validated against
-kernels/ref.py::mamba_scan_ref (interpret=True).
+kernels/ref.py::mamba_scan_ref in interpret mode; the TPU compiler refuses
+its per-row (1, CHUNK) reset blocks, so ops.mamba_scan raises for the
+pallas backend on a TPU.
 
 Validity/segment contract (kernels/core docstring, recurrence half):
 
@@ -93,24 +95,16 @@ def mamba_scan_chunked(
     C: jnp.ndarray,  # (B, L, ds)
     D: jnp.ndarray,  # (d_in,)
     *,
-    initial_state: Optional[jnp.ndarray] = None,
     reset_mask: Optional[jnp.ndarray] = None,  # (L,) or (B, L)
     valid: Optional[jnp.ndarray] = None,  # (L,) or (B, L)
     chunk: int = CHUNK,
     block_d: int = BLOCK_D,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
-    """Returns (y, final_state=None). State carries (``initial_state`` —
-    the decode path) fall back to the oracle; ``valid`` and per-row
-    ``reset_mask`` run through the kernel (module docstring)."""
-    if initial_state is not None:
-        from repro.kernels.ref import mamba_scan_ref
-
-        return mamba_scan_ref(
-            x, delta, A, Bm, C, D,
-            initial_state=initial_state, reset_mask=reset_mask, valid=valid,
-        )
-    from repro.kernels.core import as_reset_rows, as_row_mask
+    """Returns (y, final_state=None) from a zero initial state (state
+    carries run the oracle — ``ops.mamba_scan`` decides); ``valid`` and
+    per-row ``reset_mask`` run through the kernel (module docstring)."""
+    from repro.kernels.core import as_reset_rows, as_row_mask, pallas_interpret
 
     B, L, d_in = x.shape
     v2 = as_row_mask(valid, L)
@@ -149,6 +143,6 @@ def mamba_scan_chunked(
         out_specs=pl.BlockSpec((1, chunk, block_d), lambda b, di, ci: (b, ci, di)),
         out_shape=jax.ShapeDtypeStruct((B, Lp, Dp), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_d, ds), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(x, delta, A, Bm, C, D, reset)
     return out[:, :L, :d_in], None

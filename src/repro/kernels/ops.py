@@ -5,9 +5,9 @@ Backends:
             online-softmax (flash-style) scan so compiled memory stays
             O(L · chunk) even at 500k context; RWKV/Mamba use lax.scan.
             This is the default on CPU and inside the SPMD dry-run.
-  'pallas'  the Pallas TPU kernels (kernels/flash_attention.py etc.);
-            on CPU they run with interpret=True (kernel body executed by
-            the JAX interpreter) — used by the kernel validation tests.
+  'pallas'  the Pallas TPU kernels (kernels/flash_attention.py etc.),
+            compiled on a TPU and run by the JAX interpreter elsewhere
+            (kernels.core.pallas_interpret) — the CPU kernel tests.
   'ref'     the pure-jnp oracles (kernels/ref.py), O(L^2) memory; smallest
             code path, used for tests and tiny models.
 
@@ -465,6 +465,23 @@ def paged_decode_attention(
 # ---------------------------------------------------------------------------
 
 
+def _recurrent_kernel(name: str, initial_state) -> bool:
+    """Whether ``backend='pallas'`` runs the chunked Pallas kernel for a
+    recurrence. A state-carrying call (``initial_state`` given — the
+    decode path) runs the reference scan: the chunked kernels start from a
+    zero state. On a TPU the kernels' ``(1, chunk, 1, d)`` blocks are
+    refused by the compiler (block dims must be whole or 8/128-aligned),
+    so the pallas backend raises there, naming the cause."""
+    if initial_state is not None:
+        return False
+    if not _core.pallas_interpret():
+        raise NotImplementedError(
+            f"the {name} Pallas kernel does not compile for the TPU yet; "
+            "serve recurrent stacks with backend='xla' on a TPU"
+        )
+    return True
+
+
 def rwkv6(
     r, k, v, w, u, *,
     initial_state=None, reset_mask=None, valid=None, backend=None,
@@ -474,12 +491,11 @@ def rwkv6(
     contract (repro.kernels.core docstring): invalid tokens are identity
     state updates, so pow2-padded / ragged-row batches scan safely."""
     backend = backend or _DEFAULT_BACKEND
-    if backend == "pallas":
+    if backend == "pallas" and _recurrent_kernel("rwkv6", initial_state):
         from repro.kernels import rwkv6 as _rk
 
         return _rk.rwkv6_chunked(
-            r, k, v, w, u,
-            initial_state=initial_state, reset_mask=reset_mask, valid=valid,
+            r, k, v, w, u, reset_mask=reset_mask, valid=valid,
         )
     return _ref.rwkv6_ref(
         r, k, v, w, u,
@@ -493,12 +509,11 @@ def mamba_scan(
 ):
     """Mamba1 selective scan; ``reset_mask``/``valid`` as in :func:`rwkv6`."""
     backend = backend or _DEFAULT_BACKEND
-    if backend == "pallas":
+    if backend == "pallas" and _recurrent_kernel("mamba_scan", initial_state):
         from repro.kernels import mamba_scan as _ms
 
         return _ms.mamba_scan_chunked(
-            x, delta, A, Bm, C, D,
-            initial_state=initial_state, reset_mask=reset_mask, valid=valid,
+            x, delta, A, Bm, C, D, reset_mask=reset_mask, valid=valid,
         )
     return _ref.mamba_scan_ref(
         x, delta, A, Bm, C, D,

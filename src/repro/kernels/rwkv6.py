@@ -19,7 +19,9 @@ inside float32 range; the A product itself is always ≤ O(1).
 
 Grid = (B·H, n_chunks) with the chunk axis LAST (TPU grids iterate the last
 axis sequentially), so the (dk, dv) state lives in VMEM scratch across
-chunk steps. Validated against kernels/ref.py::rwkv6_ref (interpret=True).
+chunk steps. Validated against kernels/ref.py::rwkv6_ref in interpret
+mode; the TPU compiler refuses its (1, CHUNK, 1, d) blocks, so ops.rwkv6
+raises for the pallas backend on a TPU.
 
 Validity/segment contract (kernels/core docstring, recurrence half):
 
@@ -129,23 +131,16 @@ def rwkv6_chunked(
     w: jnp.ndarray,  # (B, L, H, dk) log-decay (<= 0)
     u: jnp.ndarray,  # (H, dk)
     *,
-    initial_state: Optional[jnp.ndarray] = None,
     reset_mask: Optional[jnp.ndarray] = None,  # (L,) or (B, L)
     valid: Optional[jnp.ndarray] = None,  # (L,) or (B, L)
     chunk: int = CHUNK,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
-    """Returns (y, final_state=None). State carries (``initial_state`` —
-    the decode path) fall back to the reference scan; ``valid`` and per-row
-    ``reset_mask`` run through the chunked kernel (module docstring)."""
-    if initial_state is not None:
-        from repro.kernels.ref import rwkv6_ref
-
-        return rwkv6_ref(
-            r, k, v, w, u,
-            initial_state=initial_state, reset_mask=reset_mask, valid=valid,
-        )
-    from repro.kernels.core import as_reset_rows, as_row_mask
+    """Returns (y, final_state=None) from a zero initial state (state
+    carries run the reference scan — ``ops.rwkv6`` decides); ``valid`` and
+    per-row ``reset_mask`` run through the chunked kernel (module
+    docstring)."""
+    from repro.kernels.core import as_reset_rows, as_row_mask, pallas_interpret
 
     B, L, H, dk = r.shape
     dv = v.shape[-1]
@@ -183,6 +178,6 @@ def rwkv6_chunked(
         out_specs=pl.BlockSpec((1, chunk, 1, dv), im4),
         out_shape=jax.ShapeDtypeStruct((B, Lp, H, dv), r.dtype),
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        interpret=interpret,
+        interpret=pallas_interpret(interpret),
     )(r, k, v, w, u, reset)
     return out[:, :L], None

@@ -8,7 +8,12 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import make_mesh
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto`` (GSPMD propagation —
+    the sharding mode every mesh in this repo is written for)."""
+    auto = (jax.sharding.AxisType.Auto,) * len(axes)
+    return jax.make_mesh(tuple(shape), tuple(axes), axis_types=auto)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -1,4 +1,9 @@
-"""Serving launcher — collaborative FedAttn inference on reduced configs.
+"""Serving launcher — collaborative FedAttn inference.
+
+By default the model is the reduced smoke-size variant of ``--arch``;
+``--n-layers N`` serves the published config instead, at its published
+widths and dtype with only its depth cut to N layers. Weights are random,
+drawn from ``--seed``.
 
 Demonstrates the paper's deployment story end to end: N participants hold
 private token segments; the engine runs FedAttn prefill (periodic KV
@@ -28,18 +33,31 @@ Usage:
       --sync-interval 2 --kv-ratio 0.5 --n-new 16
   PYTHONPATH=src python -m repro.launch.serve --stream --stream-requests 16 \
       --arrival-rate 4 --max-slots 8
+  PYTHONPATH=src python -m repro.launch.serve --n-layers 8 --stream \
+      --sync-interval 4 --seq-len 2048 --n-new 32  # TPU
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional
 
 import jax
 import numpy as np
 
-from repro.configs import ASSIGNED_ARCHS, get_reduced_config
+from repro.compile_cache import enable_compile_cache
+from repro.configs import ASSIGNED_ARCHS, get_config, get_reduced_config
 from repro.serving import FedAttnEngine, Request
-from repro.types import FedAttnConfig
+from repro.types import FedAttnConfig, ModelConfig
+
+
+def build_config(arch: str, n_layers: Optional[int] = None) -> ModelConfig:
+    """The model ``serve`` runs: the published config of ``arch`` with only
+    its depth cut to ``n_layers`` (widths, vocab and dtype as published),
+    or — ``n_layers=None`` — its reduced smoke-size variant."""
+    if n_layers is None:
+        return get_reduced_config(arch)
+    return get_config(arch).replace(n_layers=n_layers)
 
 
 def poisson_trace(
@@ -142,8 +160,13 @@ def run_stream(engine: FedAttnEngine, config, args) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=list(ASSIGNED_ARCHS), default="qwen2-7b")
+    ap.add_argument("--n-layers", type=int, default=None,
+                    help="serve the published config at its published "
+                         "widths with only its depth cut to N layers "
+                         "(default: the reduced smoke-size variant)")
     ap.add_argument("--participants", type=int, default=4)
     ap.add_argument("--sync-interval", type=int, default=2)
     ap.add_argument("--schedule", default="uniform")
@@ -215,7 +238,7 @@ def main() -> None:
                          "picks scan whenever the schedule allows it")
     args = ap.parse_args()
 
-    config = get_reduced_config(args.arch)
+    config = build_config(args.arch, args.n_layers)
     if config.is_encoder_decoder:
         raise SystemExit("decoder-only serving demo; enc-dec covered in examples")
     fed = FedAttnConfig(
@@ -226,11 +249,6 @@ def main() -> None:
         kv_selection=args.kv_selection,
         kv_quant=args.kv_quant,
     )
-    model_params = None
-    from repro.models import build_model
-
-    model = build_model(config)
-    model_params = model.init(jax.random.key(0))
     mesh = None
     if args.mesh:
         if not args.stream:
@@ -238,11 +256,14 @@ def main() -> None:
         from repro.launch.mesh import make_serving_mesh
 
         mesh = make_serving_mesh(args.mesh)
+    layers_mode = None if args.layers_mode == "auto" else args.layers_mode
+    if args.no_compile:
+        layers_mode = "loop"  # the eager reference reads loop-form weights
     engine = FedAttnEngine(
-        config, model_params, fedattn=fed, bucket=args.bucket,
-        layers_mode=None if args.layers_mode == "auto" else args.layers_mode,
-        mesh=mesh,
+        config, fedattn=fed, bucket=args.bucket,
+        layers_mode=layers_mode, mesh=mesh,
     )
+    engine.init_random_params(args.seed)
 
     if args.stream:
         run_stream(engine, config, args)

@@ -63,12 +63,14 @@ full-size lowering.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from dataclasses import dataclass
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.analysis.trace_guard import TraceGuard
 from repro.core.fedattn import FedAttnContext
@@ -135,7 +137,7 @@ class FedAttnEngine:
     def __init__(
         self,
         config: ModelConfig,
-        params,
+        params=None,
         *,
         fedattn: Optional[FedAttnConfig] = None,
         backend: Optional[str] = None,
@@ -144,7 +146,10 @@ class FedAttnEngine:
         mesh=None,
         kv_quant: Optional[str] = None,
     ):
-        """bucket: 'pow2' pads L/n_new to power-of-two buckets so mixed
+        """params: the weights (loop or scan form), or None to install
+        them later (:meth:`set_params` / :meth:`init_random_params`).
+
+        bucket: 'pow2' pads L/n_new to power-of-two buckets so mixed
         request lengths share compiled executables; 'none' compiles per
         exact shape. layers_mode: None (auto), 'loop', or 'scan'.
 
@@ -159,15 +164,16 @@ class FedAttnEngine:
         ``ContinuousBatchingScheduler``): the KV slot pool is sharded over
         the 'model' axis along capacity and the resident decode step runs
         flash-decoding against it (distributed/spmd_attention). Standalone
-        ``generate`` calls and admission prefills stay single-device — the
+        ``generate`` calls and admission prefills are not sharded — the
         mesh only changes where the pooled decode math runs, never its
-        numbers (parity pinned in tests/test_spmd.py)."""
+        numbers (parity pinned in tests/test_spmd.py). The weights are
+        replicated over the mesh once, when they are installed, so no
+        call re-transfers them."""
         if config.is_encoder_decoder:
             raise NotImplementedError("engine currently drives decoder-only models")
         if bucket not in ("pow2", "none"):
             raise ValueError(f"unknown bucket policy {bucket!r}")
         self.config = config
-        self.params = params
         self.fed = fedattn if fedattn is not None else config.fedattn
         if kv_quant is not None:
             self.fed = self.fed.replace(kv_quant=kv_quant)
@@ -205,7 +211,10 @@ class FedAttnEngine:
         # causal stack buckets L; n_new always buckets (extra decode steps
         # run after the kept tokens and are discarded)
         self._bucket_L_ok = self.fed.causal
+        self.params = None
         self._scan_params = None  # lazily stacked params for scan mode
+        if params is not None:
+            self.set_params(params)
         # compiled drivers, keyed by bucketed shapes + sampling mode only;
         # the guards carry the executable-budget contract (one charge per
         # distinct key — see repro.analysis.trace_guard)
@@ -215,6 +224,30 @@ class FedAttnEngine:
             "prefill": TraceGuard("engine.prefill"),
             "decode": TraceGuard("engine.decode"),
         }
+
+    # -- weights ----------------------------------------------------------------
+
+    def set_params(self, params) -> None:
+        """Install weights (loop or scan form); under a serving mesh they
+        are replicated over it here, once."""
+        if self.spmd is not None:
+            replicated = NamedSharding(self.spmd.mesh, PartitionSpec())
+            params = jax.device_put(params, replicated)
+        self.params = params
+        self._scan_params = None
+
+    def init_random_params(self, seed: int) -> None:
+        """Install random weights drawn from ``seed`` by one jitted init,
+        in the layout the compiled drivers consume: scan form
+        (``transformer.init_stacked`` — one resident copy, nothing to
+        restack) when the layers lower as a scan over the pattern period,
+        loop form otherwise. Scan-form weights serve the compiled paths
+        only (``generate(compile=False)`` needs loop form)."""
+        if self.layers_mode == "scan" and self._plan.period == len(self.config.pattern):
+            init = functools.partial(T.init_stacked, self.model)
+        else:
+            init = self.model.init
+        self.set_params(jax.jit(init)(jax.random.key(seed)))
 
     # -- protocol setup ---------------------------------------------------------
 

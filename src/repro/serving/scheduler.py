@@ -414,11 +414,12 @@ class ContinuousBatchingScheduler:
         # per-tick arrays tok/write_pos/fold are tiny; these are the wide
         # ones + the ones that cost dispatches to rebuild)
         self._slot_args = None
-        # on CPU the admission prefill caches can be allocated once per
-        # admission-batch bucket and reused (nothing donates or mutates
-        # them); accelerators donate prefill buffers, so there they are
-        # rebuilt per admit
-        self._prefill_caches: dict = {} if jax.default_backend() == "cpu" else None
+        # the admission prefill caches can be allocated once per
+        # admission-batch bucket and reused only where the prefill does not
+        # donate them (the donation policy); otherwise rebuilt per admit
+        self._prefill_caches: Optional[dict] = (
+            None if _donation_for_backend((1,)) else {}
+        )
 
     def _spmd_scope(self):
         """runtime.spmd context for tracing/running pooled executables —

@@ -1,25 +1,6 @@
 """Shared fixtures. NOTE: no xla_force_host_platform_device_count here —
 tests run on the single real CPU device by design (the multi-device SPMD
-equivalence test spawns a subprocess with its own XLA_FLAGS).
-
-Offline story: if the real `hypothesis` package is missing (air-gapped
-machines), install the vendored stub (tests/_hypothesis_stub.py) under the
-`hypothesis` name BEFORE test modules import it — property tests degrade
-to deterministic fixed-example tests instead of failing collection."""
-import importlib.util
-import pathlib
-import sys
-
-try:  # pragma: no cover - depends on environment
-    import hypothesis  # noqa: F401
-except ModuleNotFoundError:
-    _stub_path = pathlib.Path(__file__).with_name("_hypothesis_stub.py")
-    _spec = importlib.util.spec_from_file_location("hypothesis", _stub_path)
-    _mod = importlib.util.module_from_spec(_spec)
-    sys.modules["hypothesis"] = _mod
-    _spec.loader.exec_module(_mod)
-    sys.modules["hypothesis.strategies"] = _mod.strategies
-
+equivalence test spawns a subprocess with its own XLA_FLAGS)."""
 import os
 
 import jax

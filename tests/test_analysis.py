@@ -12,7 +12,6 @@ Three layers:
   architecture's serving entry points trace clean with ZERO compilations.
 """
 import jax
-import jax.experimental
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -280,7 +279,7 @@ class TestLintRules:
 
 class TestAuditChecks:
     def test_f64_regression_caught(self):
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             t = jax.jit(lambda x: jnp.asarray(x, jnp.float64) * 2).trace(
                 jnp.ones(4, jnp.float32)
             )
@@ -318,6 +317,18 @@ class TestAuditChecks:
             == []
         )
 
+    def test_donated_pytree_operand_is_its_argument_position(self):
+        """A donated cache pytree is declared as its argument position, not
+        as the flattened indices of its leaves (the form accelerators see:
+        the audit compares positions)."""
+        params = {"w": jnp.ones(2), "v": jnp.ones(2)}
+        cache = {"k": jnp.ones(2), "v": jnp.ones(3)}
+        t = jax.jit(lambda p, c: (p, c), donate_argnums=(1,)).trace(params, cache)
+        assert jaxpr_audit.donated_argnums(t) == (1,)
+        assert jaxpr_audit.audit_traced(
+            "fixture", t, donate_expected=_donation_for_backend((1,), "tpu")
+        ) == []
+
     def test_baked_in_buffer_caught(self):
         big = jnp.asarray(np.zeros((600, 600), np.float32))  # > 1 MiB
         t = jax.jit(lambda x: x + big).trace(jnp.ones((600, 600), jnp.float32))
@@ -352,7 +363,7 @@ class TestDonationPolicy:
         entries = jaxpr_audit.trace_engine_entries(eng, B=1, L=8, n_new=4)
         backend = jax.default_backend()
         for e in entries:
-            declared = tuple(sorted(e.traced.donate_argnums or ()))
+            declared = jaxpr_audit.donated_argnums(e.traced)
             assert declared == _donation_for_backend(e.cache_argnums, backend), e.name
         # and the audit agrees end-to-end
         assert jaxpr_audit.audit_entries(entries) == []

@@ -64,7 +64,7 @@ def test_next_pow2_boundaries(k):
 
 
 @given(n=st.integers(min_value=1, max_value=4096))
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)  # first example builds engines
 def test_bucket_len_and_new_policy_every_stack(n):
     """pow2 policy on every causal stack kind — attention, hybrid
     (mamba+attn) and pure-rwkv alike: both dims bucket to _next_pow2 (so 1
@@ -79,7 +79,7 @@ def test_bucket_len_and_new_policy_every_stack(n):
 
 
 @given(n=st.integers(min_value=1, max_value=4096))
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)  # first example builds an engine
 def test_bucket_none_is_identity(n):
     """bucket='none' opts out of padding entirely, both dims."""
     assert _eng("none")._bucket_len(n) == n

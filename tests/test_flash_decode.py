@@ -434,7 +434,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from repro.compat import make_mesh
 from repro.models import build_model
 from repro.serving import FedAttnEngine, Request
 from repro.serving.scheduler import ContinuousBatchingScheduler
@@ -457,7 +456,7 @@ reqs = [req(0, 14, 6), req(1, 9, 5, temp=0.7), req(2, 17, 4)]
 base = FedAttnEngine(cfg, params).generate_many(
     reqs, max_slots=2, capacity=64, kv_layout="paged", page_size=8)
 
-mesh = make_mesh((2,), ("model",))
+mesh = jax.make_mesh((2,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
 eng = FedAttnEngine(cfg, params, mesh=mesh, backend="pallas")
 sched = ContinuousBatchingScheduler(
     eng, max_slots=2, capacity=64, kv_layout="paged", page_size=8)

@@ -1,5 +1,5 @@
 """Pallas kernel validation: shape/dtype sweeps against the pure-jnp oracles
-(interpret=True — the kernel body executes on CPU)."""
+(interpret mode — the kernel body executes on CPU)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -317,3 +317,38 @@ def test_negative_kv_segments_are_padding_sentinels():
         q, k, v, q_pos=q_pos, kv_pos=kv_pos, q_seg=q_seg, kv_seg=kv_seg_pad
     )
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=3e-6)
+
+
+def _mamba_operands(B=1, L=16, d_in=16, ds=8):
+    ks = jax.random.split(jax.random.key(2), 6)
+    return dict(
+        x=jax.random.normal(ks[0], (B, L, d_in)),
+        delta=jax.nn.softplus(jax.random.normal(ks[1], (B, L, d_in))),
+        A=-jnp.exp(jax.random.normal(ks[2], (d_in, ds)) * 0.3),
+        Bm=jax.random.normal(ks[3], (B, L, ds)),
+        C=jax.random.normal(ks[4], (B, L, ds)),
+        D=jnp.zeros((d_in,)),
+    ), jax.random.normal(ks[5], (B, d_in, ds))
+
+
+def test_pallas_stateful_recurrence_runs_the_reference_scan():
+    """ops decides the kernel/reference split: a state-carrying call (the
+    decode path) on the pallas backend is exactly the reference scan."""
+    from repro.kernels import ops
+
+    a, h0 = _mamba_operands()
+    got, h = ops.mamba_scan(**a, initial_state=h0, backend="pallas")
+    want, h_want = ref.mamba_scan_ref(**a, initial_state=h0)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(h), np.asarray(h_want))
+
+
+def test_pallas_recurrence_raises_where_the_kernel_would_compile(monkeypatch):
+    """Where kernels compile (a TPU), the recurrent Pallas kernels would be
+    refused by the compiler; ops names that instead of failing inside it."""
+    from repro.kernels import core, ops
+
+    monkeypatch.setattr(core, "pallas_interpret", lambda interpret=None: False)
+    a, _ = _mamba_operands()
+    with pytest.raises(NotImplementedError, match="mamba_scan"):
+        ops.mamba_scan(**a, backend="pallas")

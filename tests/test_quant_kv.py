@@ -436,7 +436,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import json
 import jax
 import numpy as np
-from repro.compat import make_mesh
 from repro.serving import FedAttnEngine, Request
 from repro.serving.scheduler import ContinuousBatchingScheduler
 from repro.types import FedAttnConfig, LayerSpec, ModelConfig
@@ -462,7 +461,7 @@ base = ContinuousBatchingScheduler(
     kv_quant="int8",
 ).run(reqs)
 
-mesh = make_mesh((2,), ("model",))
+mesh = jax.make_mesh((2,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
 eng = FedAttnEngine(cfg, params, mesh=mesh)
 sched = ContinuousBatchingScheduler(
     eng, max_slots=2, capacity=64, kv_layout="paged", page_size=16,

@@ -5,14 +5,13 @@ import pytest
 from hypothesis import given, settings, strategies as st
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_abstract_mesh
 from repro.distributed.sharding import param_spec
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # abstract mesh over the single CPU device is fine for spec generation
-    return make_abstract_mesh((16, 16), ("data", "model"))
+    return jax.sharding.AbstractMesh((16, 16), ("data", "model"))
 
 
 def test_big_2d_gets_combined_axes(mesh):

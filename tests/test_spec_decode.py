@@ -444,7 +444,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import json
 import jax
 import numpy as np
-from repro.compat import make_mesh
 from repro.serving import FedAttnEngine, Request
 from repro.serving.scheduler import ContinuousBatchingScheduler
 from repro.types import FedAttnConfig, LayerSpec, ModelConfig
@@ -468,7 +467,7 @@ reqs = [req(0, 24, 6), req(1, 17, 4, temp=0.7), req(2, 30, 3), req(3, 9, 8)]
 single = FedAttnEngine(cfg, params)
 base = single.generate_many(reqs, max_slots=2, capacity=64)
 
-mesh = make_mesh((2,), ("model",))
+mesh = jax.make_mesh((2,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
 eng = FedAttnEngine(cfg, params, mesh=mesh)
 sched = ContinuousBatchingScheduler(eng, max_slots=2, capacity=64, spec_k=2)
 got = sched.run(reqs)

@@ -19,7 +19,6 @@ import json
 import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
 from repro.core.fedattn import FedAttnContext
 from repro.distributed import runtime
 from repro.launch import steps as S
@@ -42,7 +41,9 @@ ctx = S.build_context(cfg, L)
 # reference on the implicit single-device path
 ref = model.apply(params, tokens, ctx)
 
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh(
+    (2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+)
 tok_sh = jax.device_put(tokens, NamedSharding(mesh, P("data", "model")))
 with runtime.spmd(mesh, batch_axes=("data",)):
     got = jax.jit(lambda p, t: model.apply(p, t, ctx))(params, tok_sh)
@@ -57,7 +58,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-from repro.compat import make_mesh
 from repro.distributed import runtime
 from repro.launch import steps as S
 from repro.models.transformer import TransformerLM
@@ -88,7 +88,9 @@ for m, (p, spec) in enumerate(zip(params["layers"], cfg.layer_specs())):
 
 ref_logits, _ = model.decode_step(params, cache, tokens[:, L:], L, ctx, step=0)
 
-mesh = make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh(
+    (2, 4), ("data", "model"), axis_types=(jax.sharding.AxisType.Auto,) * 2
+)
 cache_sh = [
     {k: jax.device_put(v, NamedSharding(mesh, P("data", "model", None, None)))
      for k, v in c.items()}
@@ -109,7 +111,6 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
 import json
 import jax, jax.numpy as jnp
 import numpy as np
-from repro.compat import make_mesh
 from repro.serving import FedAttnEngine, Request
 from repro.serving.scheduler import ContinuousBatchingScheduler
 from repro.types import FedAttnConfig, LayerSpec, ModelConfig
@@ -135,7 +136,7 @@ reqs = [req(0, 24, 6), req(1, 17, 4, temp=0.7), req(2, 30, 3),
 single = FedAttnEngine(cfg, params)
 base = single.generate_many(reqs, max_slots=2, capacity=64)
 
-mesh = make_mesh((2,), ("model",))
+mesh = jax.make_mesh((2,), ("model",), axis_types=(jax.sharding.AxisType.Auto,))
 eng = FedAttnEngine(cfg, params, mesh=mesh)
 sched = ContinuousBatchingScheduler(eng, max_slots=2, capacity=64)
 got = sched.run(reqs)
