@@ -175,7 +175,7 @@ def prefill_logits(engine, req):
     """First-token logits (V,) from the engine's bucketed prefill."""
     toks = req.tokens[None]
     L = toks.shape[1]
-    Lp = engine._bucket_len(L)
+    Lp = engine.bucket_len(L)
     cap = Lp + engine._bucket_new(req.n_new)
     plan = engine._plan if engine.layers_mode == "scan" else None
     cache = engine.model.init_cache(1, cap, plan=plan)

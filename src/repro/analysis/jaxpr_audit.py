@@ -241,7 +241,7 @@ def trace_engine_entries(
     prefill, per-row coalesced prefill, and the decode driver. Argument
     construction mirrors ``generate``/``_admit_group`` exactly — the audit
     sees the same executables serving does."""
-    Lp = engine._bucket_len(L)
+    Lp = engine.bucket_len(L)
     Nb = engine._bucket_new(n_new)
     capacity = Lp + Nb
     plan = engine._plan if engine.layers_mode == "scan" else None
@@ -351,7 +351,7 @@ def trace_scheduler_entries(scheduler) -> list[EntryPoint]:
         # gathered from the pool through source page tables; write
         # frontiers are traced per-row, the pool is NOT donated (the
         # caller keeps reading it)
-        Ls = min(C, eng._bucket_len(2))
+        Ls = min(C, eng.bucket_len(2))
         fn = eng._suffix_prefill_fn(1, Ls, C, None)
         traced = fn.trace(
             params, sched.cache,
@@ -551,7 +551,7 @@ def audit_engine(
     if with_pool:
         from repro.serving.scheduler import ContinuousBatchingScheduler
 
-        cap = engine._bucket_len(L) + engine._bucket_new(n_new)
+        cap = engine.bucket_len(L) + engine._bucket_new(n_new)
         spmd = getattr(engine, "spmd", None)
         if spmd is not None:
             n = spmd.mesh.shape[spmd.cache_axes[0]]

@@ -269,6 +269,7 @@ def flash_attention(
             pltpu.VMEM((block_q, dh), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention",
     )(
         q.reshape(B, Lq_p, nq * dh), k.reshape(B, Lk_p, nkv * dh),
         v.reshape(B, Lk_p, nkv * dh), q_pos, kv_pos, q_seg, kv_seg, contrib,

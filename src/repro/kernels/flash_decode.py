@@ -320,6 +320,7 @@ def paged_flash_decode(
         ),
         out_shape=out_shape,
         interpret=interpret,
+        name="flash_decode",
     )(pages, *operands)
     p_p = outs[3] if return_mass else None
     return _finish(

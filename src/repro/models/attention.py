@@ -20,6 +20,7 @@ import jax.numpy as jnp
 from repro.core.fedattn import FedAttnContext
 from repro.kernels import ops
 from repro.models import layers as L
+from repro.models import scopes
 from repro.types import LayerSpec, ModelConfig
 
 Params = dict
@@ -216,7 +217,8 @@ def attention_decode_block(
     softmax combine (distributed/spmd_attention.py), with the segment
     vectors carrying the same per-row masking as the single-device path."""
     theta = _rope_theta_for(spec, config)
-    q, k_new, v_new = _project_qkv(p, x, config, ctx.positions, theta)
+    with jax.named_scope(scopes.QKV):
+        q, k_new, v_new = _project_qkv(p, x, config, ctx.positions, theta)
     S_new = x.shape[1]
 
     from repro.distributed import runtime
@@ -225,13 +227,85 @@ def attention_decode_block(
     k_scales = v_scales = None
     if kv_scales is not None:
         k_scales, v_scales = kv_scales
+    with jax.named_scope(scopes.KV_WRITE):
+        k_cache, v_cache, k_scales, v_scales = _write_kv(
+            k_cache, v_cache, k_new, v_new, cache_len, pages, spmd,
+            k_scales, v_scales,
+        )
+    if sync is None:
+        sync = ctx.schedule.is_sync(layer_idx)
+    B = x.shape[0]
+    if pages is None and S_new > 1:
+        attn_scope = scopes.PREFILL_ATTENTION
+    else:
+        attn_scope = scopes.DECODE_ATTENTION
+
+    if pages is not None:
+        with jax.named_scope(attn_scope):
+            out, attn_mass = _paged_attend(
+                q, k_cache, v_cache, pages, ctx, spec, config, sync, spmd,
+                backend, contributed, k_scales, v_scales, attn_mass,
+            )
+        with jax.named_scope(scopes.ATTN_OUT):
+            y = jnp.einsum("bse,ed->bsd", out.reshape(B, S_new, -1), p["wo"])
+        if kv_scales is not None:
+            res = (y, k_cache, v_cache, k_scales, v_scales)
+        else:
+            res = (y, k_cache, v_cache)
+        return res + ((attn_mass,) if attn_mass is not None else ())
+
+    with jax.named_scope(attn_scope):
+        if spmd:
+            from repro.distributed import spmd_attention
+
+            publisher_lo = (
+                ctx.partition.publisher_start(ctx.config.publisher_index)
+                if ctx.enabled else 0
+            )
+            out = spmd_attention.decode_attention(
+                q, k_cache, v_cache,
+                q_pos=ctx.positions,
+                kv_pos=ctx.kv_positions,
+                q_seg=ctx.segments if ctx.enabled else None,
+                kv_seg=ctx.kv_segments if ctx.enabled else None,
+                publisher_lo=publisher_lo,
+                sync=sync or not ctx.enabled,
+                window=spec.window,
+                soft_cap=config.attn_soft_cap,
+            )
+        else:
+            out = ops.decode_attention(
+                q, k_cache, v_cache,
+                q_pos=ctx.positions,
+                kv_pos=ctx.kv_positions,
+                q_seg=ctx.segments if ctx.enabled else None,
+                kv_seg=ctx.kv_segments if ctx.enabled else None,
+                causal=True,
+                local_only=(not sync) and ctx.enabled,
+                contributed=contributed if (sync and ctx.enabled) else None,
+                window=spec.window,
+                soft_cap=config.attn_soft_cap,
+                backend=backend,
+            )
+    with jax.named_scope(scopes.ATTN_OUT):
+        y = jnp.einsum("bse,ed->bsd", out.reshape(B, S_new, -1), p["wo"])
+    return y, k_cache, v_cache
+
+
+def _write_kv(k_cache, v_cache, k_new, v_new, cache_len, pages, spmd,
+              k_scales, v_scales):
+    """The decode block's KV write (see :func:`attention_decode_block`):
+    through the page tables into the shared pool, per-row frontiers into
+    dense rows, or one slice at a scalar frontier. Returns the caches and
+    the scales (None unless the pool is quantized)."""
+    S_new = k_new.shape[1]
     if pages is not None:
         from repro.serving import paging
 
         if spmd:
             from repro.distributed import spmd_attention
 
-            if kv_scales is not None:
+            if k_scales is not None:
                 k_cache, v_cache, k_scales, v_scales = (
                     spmd_attention.paged_kv_write(
                         k_cache, v_cache, k_new, v_new, pages, cache_len,
@@ -245,7 +319,7 @@ def attention_decode_block(
         else:
             N, ps = k_cache.shape[0], k_cache.shape[1]
             Cp = pages.shape[1] * ps
-            B = x.shape[0]
+            B = k_new.shape[0]
             pos = jnp.broadcast_to(
                 jnp.reshape(cache_len, (-1, 1)) + jnp.arange(S_new)[None, :],
                 (B, S_new),
@@ -255,7 +329,7 @@ def attention_decode_block(
             # positions past the table (retired slots coasting) must not
             # clamp into a real page — force the sentinel so they drop
             page_idx = jnp.where(pos < Cp, page_idx, N)
-            if kv_scales is not None:
+            if k_scales is not None:
                 from repro.serving import quant
 
                 k_cache, k_scales = quant.paged_write(
@@ -281,88 +355,41 @@ def attention_decode_block(
                 k_cache, v_cache, k_new, v_new, cache_len
             )
         else:
-            rows = jnp.arange(x.shape[0])[:, None]
+            rows = jnp.arange(k_new.shape[0])[:, None]
             cols = cache_len[:, None] + jnp.arange(S_new)[None, :]
             k_cache = k_cache.at[rows, cols].set(k_new.astype(k_cache.dtype))
             v_cache = v_cache.at[rows, cols].set(v_new.astype(v_cache.dtype))
     else:
         k_cache = jax.lax.dynamic_update_slice_in_dim(k_cache, k_new.astype(k_cache.dtype), cache_len, axis=1)
         v_cache = jax.lax.dynamic_update_slice_in_dim(v_cache, v_new.astype(v_cache.dtype), cache_len, axis=1)
-    if sync is None:
-        sync = ctx.schedule.is_sync(layer_idx)
+    return k_cache, v_cache, k_scales, v_scales
 
-    if pages is not None:
-        publisher_lo = (
-            ctx.partition.publisher_start(ctx.config.publisher_index)
-            if ctx.enabled else 0
+
+def _paged_attend(q, k_cache, v_cache, pages, ctx, spec, config, sync, spmd,
+                  backend, contributed, k_scales, v_scales, attn_mass):
+    """The paged pool's attention (see :func:`attention_decode_block`).
+    Returns the attention output and the updated attention-mass buffer
+    (None when the pool carries none)."""
+    publisher_lo = (
+        ctx.partition.publisher_start(ctx.config.publisher_index)
+        if ctx.enabled else 0
+    )
+    want_mass = attn_mass is not None
+    if (
+        want_mass and sync and ctx.enabled and contributed is None
+        and ctx.config.kv_selection == "attnmass"
+        and ctx.config.kv_exchange_ratio < 1.0
+    ):
+        from repro.distributed import spmd_attention
+
+        contributed = spmd_attention.decode_exchange_mask(
+            attn_mass, ctx.config.kv_exchange_ratio
         )
-        want_mass = attn_mass is not None
-        if (
-            want_mass and sync and ctx.enabled and contributed is None
-            and ctx.config.kv_selection == "attnmass"
-            and ctx.config.kv_exchange_ratio < 1.0
-        ):
-            from repro.distributed import spmd_attention
-
-            contributed = spmd_attention.decode_exchange_mask(
-                attn_mass, ctx.config.kv_exchange_ratio
-            )
-        if spmd:
-            from repro.distributed import spmd_attention
-
-            out = spmd_attention.paged_decode_attention(
-                q, k_cache, v_cache, pages,
-                q_pos=ctx.positions,
-                kv_pos=ctx.kv_positions,
-                q_seg=ctx.segments if ctx.enabled else None,
-                kv_seg=ctx.kv_segments if ctx.enabled else None,
-                publisher_lo=publisher_lo,
-                sync=sync or not ctx.enabled,
-                window=spec.window,
-                soft_cap=config.attn_soft_cap,
-                kv_scales=kv_scales if kv_scales is None
-                else (k_scales, v_scales),
-                contributed=contributed if (sync and ctx.enabled) else None,
-                backend=backend,
-                return_mass=want_mass,
-            )
-        else:
-            out = ops.paged_decode_attention(
-                q, k_cache, v_cache, pages,
-                q_pos=ctx.positions,
-                kv_pos=ctx.kv_positions,
-                q_seg=ctx.segments if ctx.enabled else None,
-                kv_seg=ctx.kv_segments if ctx.enabled else None,
-                causal=True,
-                local_only=(not sync) and ctx.enabled,
-                contributed=contributed if (sync and ctx.enabled) else None,
-                window=spec.window,
-                soft_cap=config.attn_soft_cap,
-                backend=backend,
-                k_scales=k_scales,
-                v_scales=v_scales,
-                return_mass=want_mass,
-            )
-        if want_mass:
-            out, mass = out
-            attn_mass = attn_mass + mass
-        B = x.shape[0]
-        y = jnp.einsum("bse,ed->bsd", out.reshape(B, S_new, -1), p["wo"])
-        if kv_scales is not None:
-            res = (y, k_cache, v_cache, k_scales, v_scales)
-        else:
-            res = (y, k_cache, v_cache)
-        return res + ((attn_mass,) if want_mass else ())
-
     if spmd:
         from repro.distributed import spmd_attention
 
-        publisher_lo = (
-            ctx.partition.publisher_start(ctx.config.publisher_index)
-            if ctx.enabled else 0
-        )
-        out = spmd_attention.decode_attention(
-            q, k_cache, v_cache,
+        out = spmd_attention.paged_decode_attention(
+            q, k_cache, v_cache, pages,
             q_pos=ctx.positions,
             kv_pos=ctx.kv_positions,
             q_seg=ctx.segments if ctx.enabled else None,
@@ -371,26 +398,29 @@ def attention_decode_block(
             sync=sync or not ctx.enabled,
             window=spec.window,
             soft_cap=config.attn_soft_cap,
+            kv_scales=None if k_scales is None else (k_scales, v_scales),
+            contributed=contributed if (sync and ctx.enabled) else None,
+            backend=backend,
+            return_mass=want_mass,
         )
-        B = x.shape[0]
-        y = jnp.einsum("bse,ed->bsd", out.reshape(B, S_new, -1), p["wo"])
-        return y, k_cache, v_cache
-
-    seg = ctx.segments if ctx.enabled else None
-    kv_seg = ctx.kv_segments if ctx.enabled else None
-    out = ops.decode_attention(
-        q, k_cache, v_cache,
-        q_pos=ctx.positions,
-        kv_pos=ctx.kv_positions,
-        q_seg=seg,
-        kv_seg=kv_seg,
-        causal=True,
-        local_only=(not sync) and ctx.enabled,
-        contributed=contributed if (sync and ctx.enabled) else None,
-        window=spec.window,
-        soft_cap=config.attn_soft_cap,
-        backend=backend,
-    )
-    B = x.shape[0]
-    y = jnp.einsum("bse,ed->bsd", out.reshape(B, S_new, -1), p["wo"])
-    return y, k_cache, v_cache
+    else:
+        out = ops.paged_decode_attention(
+            q, k_cache, v_cache, pages,
+            q_pos=ctx.positions,
+            kv_pos=ctx.kv_positions,
+            q_seg=ctx.segments if ctx.enabled else None,
+            kv_seg=ctx.kv_segments if ctx.enabled else None,
+            causal=True,
+            local_only=(not sync) and ctx.enabled,
+            contributed=contributed if (sync and ctx.enabled) else None,
+            window=spec.window,
+            soft_cap=config.attn_soft_cap,
+            backend=backend,
+            k_scales=k_scales,
+            v_scales=v_scales,
+            return_mass=want_mass,
+        )
+    if want_mass:
+        out, mass = out
+        attn_mass = attn_mass + mass
+    return out, attn_mass

@@ -27,6 +27,7 @@ from repro.core.fedattn import FedAttnContext
 from repro.models import attention as A
 from repro.models import layers as L
 from repro.models import moe as M
+from repro.models import scopes
 from repro.models import ssm as S
 from repro.types import LayerSpec, ModelConfig
 
@@ -437,33 +438,34 @@ def apply_layer_decode(
         )
         new_cache["state"], new_cache["shift_t"] = st, sh
     x = x + o
-    h2 = L.apply_norm(p["norm2"], x, config)
-    if spec.kind == "rwkv":
-        # same single-token/bulk split as time-mix: S=1 decode continues
-        # the shift carry (sync semantics), bulk prefill-via-decode honors
-        # the layer's real flag so local-layer channel-mix token shifts
-        # mask at segment boundaries exactly as the forward path does
-        f, sh2 = S.rwkv_channel_mix(
-            p["cmix"], h2, ctx, config, sync=ssm_sync,
-            shifted=cache["shift_c"],
-        )
-        new_cache["shift_c"] = sh2
-    elif spec.moe:
-        from repro.distributed import runtime as _rt
+    with jax.named_scope(scopes.MLP):
+        h2 = L.apply_norm(p["norm2"], x, config)
+        if spec.kind == "rwkv":
+            # same single-token/bulk split as time-mix: S=1 decode continues
+            # the shift carry (sync semantics), bulk prefill-via-decode honors
+            # the layer's real flag so local-layer channel-mix token shifts
+            # mask at segment boundaries exactly as the forward path does
+            f, sh2 = S.rwkv_channel_mix(
+                p["cmix"], h2, ctx, config, sync=ssm_sync,
+                shifted=cache["shift_c"],
+            )
+            new_cache["shift_c"] = sh2
+        elif spec.moe:
+            from repro.distributed import runtime as _rt
 
-        if moe_impl == "ragged" and _rt.active():
-            from repro.distributed import spmd_moe
+            if moe_impl == "ragged" and _rt.active():
+                from repro.distributed import spmd_moe
 
-            if spmd_moe.applicable(config, h2.shape[1]):
-                f = spmd_moe.moe_expert_parallel(p["moe"], h2, config)
-            else:
+                if spmd_moe.applicable(config, h2.shape[1]):
+                    f = spmd_moe.moe_expert_parallel(p["moe"], h2, config)
+                else:
+                    f = M.apply_moe_ragged(p["moe"], h2, config)
+            elif moe_impl == "ragged":
                 f = M.apply_moe_ragged(p["moe"], h2, config)
-        elif moe_impl == "ragged":
-            f = M.apply_moe_ragged(p["moe"], h2, config)
+            else:
+                f = M.apply_moe(p["moe"], h2, config)
         else:
-            f = M.apply_moe(p["moe"], h2, config)
-    else:
-        f = L.apply_ffn(p["ffn"], h2, config)
+            f = L.apply_ffn(p["ffn"], h2, config)
     return x + f, new_cache
 
 
@@ -626,7 +628,8 @@ def apply_layers_decode_scan(
     else:
         body = lambda h, xs: unit(h, xs[0], xs[1], xs[2])
         xs = (params["stacked"], cache["stacked"], contrib_body)
-    x, new_stacked = jax.lax.scan(body, x, xs)
+    with jax.named_scope(scopes.LAYER_SCAN):
+        x, new_stacked = jax.lax.scan(body, x, xs)
 
     new_rem = []
     ri = plan.n_periods * spp
@@ -869,8 +872,9 @@ class TransformerLM:
                 new_cache.append(c)
         else:
             raise ValueError(f"unknown decode mode {mode!r}")
-        x = L.apply_norm(params["final_norm"], x, cfg)
-        logits = L.apply_lm_head(params["head"], params["embed"], x, cfg)
+        with jax.named_scope(scopes.HEAD_SAMPLE):
+            x = L.apply_norm(params["final_norm"], x, cfg)
+            logits = L.apply_lm_head(params["head"], params["embed"], x, cfg)
         return logits, new_cache
 
 

@@ -79,6 +79,7 @@ from repro.configs import schedule_from_config
 from repro.kernels.core import PAD_SEGMENT
 from repro.models import build_model
 from repro.models import layers as LY
+from repro.models import scopes
 from repro.models import transformer as T
 from repro.types import FedAttnConfig, ModelConfig
 
@@ -312,7 +313,7 @@ class FedAttnEngine:
         """Length of the decode driver's pretty-printed jaxpr — a proxy for
         traced-HLO size. O(period) in scan mode (depth-independent),
         O(n_layers) in loop mode; tests/benchmarks pin the scaling."""
-        Lp, Nb = self._bucket_len(L), self._bucket_new(n_new)
+        Lp, Nb = self.bucket_len(L), self._bucket_new(n_new)
         capacity = Lp + Nb
         plan = self._plan if self.layers_mode == "scan" else None
         cache = self.model.init_cache(B, capacity, plan=plan)
@@ -347,10 +348,15 @@ class FedAttnEngine:
                 )
         return self._scan_params
 
-    def _bucket_len(self, L: int) -> int:
+    def bucket_len(self, L: int) -> int:
+        """The prefill length a prompt of ``L`` tokens is padded to: the
+        next power of two under the ``pow2`` policy, else ``L``."""
         if self.bucket == "pow2" and self._bucket_L_ok:
             return _next_pow2(L)
         return L
+
+    # the benchmark's warm-up (bench/harness.warm_up) still asks this name
+    _bucket_len = bucket_len
 
     def _bucket_new(self, n_new: int) -> int:
         if self.bucket == "pow2":
@@ -375,7 +381,7 @@ class FedAttnEngine:
         sampled = temperature > 0.0 and rng is not None
 
         if compile:
-            Lp = self._bucket_len(L)
+            Lp = self.bucket_len(L)
             Nb = self._bucket_new(n_new)
             capacity = Lp + Nb
             plan = self._plan if self.layers_mode == "scan" else None
@@ -550,8 +556,8 @@ class FedAttnEngine:
         proto = self._proto_ctx(capacity)
         round_of = [self._round_of(m) for m in range(cfg.n_layers)]
 
-        def run(params, cache, tokens, real_len, q_pos, q_seg, kv_pos, kv_seg,
-                contributed, extra):
+        def prefill(params, cache, tokens, real_len, q_pos, q_seg, kv_pos,
+                    kv_seg, contributed, extra):
             if contributed is not None and contributed.ndim == 3:
                 # (B, rounds, capacity) → rounds-first, (rounds, B, capacity)
                 contributed = jnp.swapaxes(contributed, 0, 1)
@@ -575,16 +581,17 @@ class FedAttnEngine:
                         backend=backend, contributed=row,
                     )
             # LM head on the last real position only (L may be < Lp)
-            if per_row:
-                x = jnp.take_along_axis(x, (real_len - 1)[:, None, None], axis=1)
-            else:
-                x = jax.lax.dynamic_slice_in_dim(x, real_len - 1, 1, axis=1)
-            x = LY.apply_norm(params["final_norm"], x, cfg)
-            logits = LY.apply_lm_head(params["head"], params["embed"], x, cfg)
+            with jax.named_scope(scopes.HEAD_SAMPLE):
+                if per_row:
+                    x = jnp.take_along_axis(x, (real_len - 1)[:, None, None], axis=1)
+                else:
+                    x = jax.lax.dynamic_slice_in_dim(x, real_len - 1, 1, axis=1)
+                x = LY.apply_norm(params["final_norm"], x, cfg)
+                logits = LY.apply_lm_head(params["head"], params["embed"], x, cfg)
             return logits[:, 0], cache
 
         self._trace_guards["prefill"].charge(key)
-        fn = jax.jit(run, donate_argnums=_donation_for_backend((1,)))
+        fn = jax.jit(prefill, donate_argnums=_donation_for_backend((1,)))
         self._prefill_fns[key] = fn
         return fn
 
@@ -616,8 +623,8 @@ class FedAttnEngine:
         proto = self._proto_ctx(capacity)
         round_of = [self._round_of(m) for m in range(cfg.n_layers)]
 
-        def run(params, pool, src_pages, tokens, real_len, write_lo,
-                q_seg, kv_pos, kv_seg, contributed):
+        def suffix_prefill(params, pool, src_pages, tokens, real_len, write_lo,
+                           q_seg, kv_pos, kv_seg, contributed):
             if contributed is not None and contributed.ndim == 3:
                 contributed = jnp.swapaxes(contributed, 0, 1)
             cache = T.gather_paged_cache(pool, src_pages)
@@ -641,13 +648,14 @@ class FedAttnEngine:
                         p, cache[m], x, write_lo, dctx, m, spec, cfg,
                         backend=backend, contributed=row,
                     )
-            x = jnp.take_along_axis(x, (real_len - 1)[:, None, None], axis=1)
-            x = LY.apply_norm(params["final_norm"], x, cfg)
-            logits = LY.apply_lm_head(params["head"], params["embed"], x, cfg)
+            with jax.named_scope(scopes.HEAD_SAMPLE):
+                x = jnp.take_along_axis(x, (real_len - 1)[:, None, None], axis=1)
+                x = LY.apply_norm(params["final_norm"], x, cfg)
+                logits = LY.apply_lm_head(params["head"], params["embed"], x, cfg)
             return logits[:, 0], cache
 
         self._trace_guards["prefill"].charge(key)
-        fn = jax.jit(run, donate_argnums=_donation_for_backend(()))
+        fn = jax.jit(suffix_prefill, donate_argnums=_donation_for_backend(()))
         self._prefill_fns[key] = fn
         return fn
 
@@ -675,8 +683,8 @@ class FedAttnEngine:
         # are all overridden below.
         proto = self._proto_ctx(capacity)
 
-        def run(params, cache, tok0, real_len, rng, temp,
-                q_pos0, q_seg, kv_pos, kv_seg):
+        def decode_driver(params, cache, tok0, real_len, rng, temp,
+                          q_pos0, q_seg, kv_pos, kv_seg):
             tpl = dataclasses.replace(
                 proto, positions=q_pos0, segments=q_seg,
                 kv_positions=kv_pos, kv_segments=kv_seg, contributed=None,
@@ -690,15 +698,17 @@ class FedAttnEngine:
                     step=step, backend=backend, dctx=dctx, mode=mode,
                     plan=plan,
                 )
-                nxt_logits = logits[:, -1]
-                if sampled:
-                    r = jax.random.fold_in(rng, step + 1)
-                    nxt = jax.random.categorical(
-                        r, nxt_logits.astype(jnp.float32) / temp
-                    )
-                else:
-                    nxt = jnp.argmax(nxt_logits, axis=-1)
-                return (cache, nxt), (nxt, _token_logprob(nxt_logits, nxt))
+                with jax.named_scope(scopes.HEAD_SAMPLE):
+                    nxt_logits = logits[:, -1]
+                    if sampled:
+                        r = jax.random.fold_in(rng, step + 1)
+                        nxt = jax.random.categorical(
+                            r, nxt_logits.astype(jnp.float32) / temp
+                        )
+                    else:
+                        nxt = jnp.argmax(nxt_logits, axis=-1)
+                    lp = _token_logprob(nxt_logits, nxt)
+                return (cache, nxt), (nxt, lp)
 
             (cache, _), (toks, lps) = jax.lax.scan(
                 body, (cache, tok0), jnp.arange(n_steps - 1)
@@ -707,7 +717,7 @@ class FedAttnEngine:
 
         # Donate the cache so the compiled step updates it in place.
         self._trace_guards["decode"].charge(key)
-        fn = jax.jit(run, donate_argnums=_donation_for_backend((1,)))
+        fn = jax.jit(decode_driver, donate_argnums=_donation_for_backend((1,)))
         self._decode_fns[key] = fn
         return fn
 

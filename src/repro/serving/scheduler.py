@@ -114,12 +114,14 @@ import numpy as np
 from repro.analysis.trace_guard import TraceGuard
 from repro.core.partition import Partition
 from repro.kernels.core import PAD_SEGMENT
+from repro.models import scopes
 from repro.models import transformer as T
 from repro.serving import paging
 from repro.serving.engine import (
     GenerationResult, _donation_for_backend, _next_pow2, _token_logprob,
     _verify_candidates,
 )
+from repro.serving.spans import span
 from repro.serving.spec import resolve_drafter
 
 
@@ -365,7 +367,10 @@ class ContinuousBatchingScheduler:
         self.stats = {
             "full_prefills": 0,
             "suffix_prefills": 0,
-            "prefill_tokens": 0,
+            "prefill_tokens": 0,  # real prompt tokens prefilled
+            # rows x bucketed length of every admission prefill: real
+            # tokens plus length and batch padding
+            "prefill_padded_tokens": 0,
             "peak_resident": 0,
             "peak_resident_tokens": 0,
             # speculative counters (stay 0 for spec_k=0 pools):
@@ -482,7 +487,7 @@ class ContinuousBatchingScheduler:
                 raise ValueError("scheduler requests are single-sequence (B=1)")
             toks = toks[0]
         L = int(toks.shape[0])
-        Lp = self.engine._bucket_len(L)
+        Lp = self.engine.bucket_len(L)
         if max(Lp, L + request.n_new) > self.capacity:
             raise ValueError(
                 f"request needs {max(Lp, L + request.n_new)} KV pages "
@@ -519,7 +524,7 @@ class ContinuousBatchingScheduler:
         need = 2
         for r in requests:
             L = int(jnp.asarray(r.tokens).reshape(-1).shape[0])
-            need = max(need, engine._bucket_len(L), L + r.n_new)
+            need = max(need, engine.bucket_len(L), L + r.n_new)
         spmd = getattr(engine, "spmd", None)
         if spmd is not None:
             n = spmd.mesh.shape[spmd.cache_axes[0]]
@@ -744,145 +749,155 @@ class ContinuousBatchingScheduler:
         eng = self.engine
         B = len(adms)
         C = self._cap
+        rids = [a["rid"] for a in adms]
 
-        tokens = np.zeros((B, Lp), np.int32)
-        real_len = np.ones(B, np.int32)
-        write_lo = np.zeros(B, np.int32)
-        q_seg = np.full((B, Lp), PAD_SEGMENT, np.int32)
-        kv_seg = np.zeros((B, C), np.int32)
-        temps = np.ones(B, np.float32)
-        sampled = np.zeros(B, bool)
-        key_data = np.zeros((B,) + self._key_shape, self._key_dtype)
-        contrib_rows = []
-        for i, a in enumerate(adms):
-            req, ctx, L, d = a["req"], a["ctx"], a["L"], a["d"]
-            tokens[i, : L - d] = np.asarray(req.tokens)[d:]
-            real_len[i] = L - d
-            write_lo[i] = d
-            q_seg[i, : L - d] = np.asarray(ctx.segments)[d:]
-            kv_seg[i] = np.asarray(ctx.decode_kv_segments(C))
-            temps[i] = max(req.temperature, 1e-6)
-            sampled[i] = req.temperature > 0.0 and req.rng is not None
-            key = req.rng if req.rng is not None else jax.random.key(0)
-            key_data[i] = np.asarray(jax.random.key_data(key))
-            if ctx.contributed is not None:
-                rounds = ctx.contributed.shape[0]
-                row = np.zeros((rounds, C), bool)
-                row[:, : ctx.contributed.shape[1]] = np.asarray(ctx.contributed)
-                contrib_rows.append(row)
-        n_rounds = contrib_rows[0].shape[0] if contrib_rows else None
+        with span("serve.admit.pack", rids=rids):
+            tokens = np.zeros((B, Lp), np.int32)
+            real_len = np.ones(B, np.int32)
+            write_lo = np.zeros(B, np.int32)
+            q_seg = np.full((B, Lp), PAD_SEGMENT, np.int32)
+            kv_seg = np.zeros((B, C), np.int32)
+            temps = np.ones(B, np.float32)
+            sampled = np.zeros(B, bool)
+            key_data = np.zeros((B,) + self._key_shape, self._key_dtype)
+            contrib_rows = []
+            for i, a in enumerate(adms):
+                req, ctx, L, d = a["req"], a["ctx"], a["L"], a["d"]
+                tokens[i, : L - d] = np.asarray(req.tokens)[d:]
+                real_len[i] = L - d
+                write_lo[i] = d
+                q_seg[i, : L - d] = np.asarray(ctx.segments)[d:]
+                kv_seg[i] = np.asarray(ctx.decode_kv_segments(C))
+                temps[i] = max(req.temperature, 1e-6)
+                sampled[i] = req.temperature > 0.0 and req.rng is not None
+                key = req.rng if req.rng is not None else jax.random.key(0)
+                key_data[i] = np.asarray(jax.random.key_data(key))
+                if ctx.contributed is not None:
+                    rounds = ctx.contributed.shape[0]
+                    row = np.zeros((rounds, C), bool)
+                    row[:, : ctx.contributed.shape[1]] = np.asarray(ctx.contributed)
+                    contrib_rows.append(row)
+            n_rounds = contrib_rows[0].shape[0] if contrib_rows else None
 
-        Bp = self._admit_batch_size(
-            B, Lp, n_rounds, "suffix" if suffix else True
-        )
-        pad = lambda a: np.concatenate(
-            [a, np.broadcast_to(a[:1], (Bp - B,) + a.shape[1:])]
-        ) if Bp > B else a  # padding rows replicate request 0
-        contributed = None
-        if contrib_rows:
-            contributed = jnp.asarray(pad(np.stack(contrib_rows)))
-        if suffix:
-            # gather tables: padding rows stay all-sentinel (clamped
-            # garbage gather; their compute is discarded anyway)
-            src = np.full((Bp, self._pp), self.num_pages, np.int32)
-            src[:B] = np.stack([a["src"] for a in adms])
-            fn = eng._suffix_prefill_fn(Bp, Lp, C, n_rounds)
-            last, one = fn(
-                eng._run_params(), self.cache, jnp.asarray(src),
-                jnp.asarray(pad(tokens)), jnp.asarray(pad(real_len)),
-                jnp.asarray(pad(write_lo)), jnp.asarray(pad(q_seg)),
-                jnp.arange(C, dtype=jnp.int32), jnp.asarray(pad(kv_seg)),
-                contributed,
+            Bp = self._admit_batch_size(
+                B, Lp, n_rounds, "suffix" if suffix else True
             )
-        else:
-            one = None
-            if self._prefill_caches is not None:
-                one = self._prefill_caches.get(Bp)
-            if one is None:
-                one = eng.model.init_cache(Bp, C, plan=self._plan)
-                if self._prefill_caches is not None:
-                    self._prefill_caches[Bp] = one
-            fn = eng._prefill_fn(Bp, Lp, C, n_rounds, False, per_row=True)
-            last, one = fn(
-                eng._run_params(), one, jnp.asarray(pad(tokens)),
-                jnp.asarray(pad(real_len)), jnp.arange(Lp, dtype=jnp.int32),
-                jnp.asarray(pad(q_seg)), jnp.arange(C, dtype=jnp.int32),
-                jnp.asarray(pad(kv_seg)), contributed, None,
-            )
-        tok0, lp0 = self._admit_finish_fn()(
-            last, jnp.asarray(pad(temps)), jnp.asarray(pad(key_data)),
-            jnp.asarray(pad(sampled)),
-        )
-        # scatter the real rows into their slots (padding rows get an
-        # out-of-range index and drop via scatter OOB semantics)
-        slot_idx = np.full(Bp, self.max_slots, np.int32)
-        slot_idx[:B] = slots
-        if self._paged:
-            dst = np.full((Bp, self._pp), self.num_pages, np.int32)
-            dst[:B] = np.stack([a["dst"] for a in adms])
-            self.cache = self._slot_write_fn()(
-                self.cache, one, jnp.asarray(slot_idx), jnp.asarray(dst)
-            )
-        else:
-            self.cache = self._slot_write_fn()(
-                self.cache, one, jnp.asarray(slot_idx)
-            )
-
-        tok0 = np.asarray(tok0)
-        lp0 = np.asarray(lp0)
-        t_now = time.perf_counter()  # tok0 materialized ⇒ first token exists
-        for i, a in enumerate(adms):
-            slot, ctx, req, rid = slots[i], a["ctx"], a["req"], a["rid"]
-            L, d = a["L"], a["d"]
-            self._tok[slot] = int(tok0[i])
-            self._write_pos[slot] = L  # tok0's KV goes to position L next tick
-            self._fold[slot] = 1  # token m samples with fold_in(rng, m)
-            self._qseg[slot] = ctx.partition.publisher(ctx.config.publisher_index)
-            self._kvseg[slot] = kv_seg[i]
-            self._temps[slot] = temps[i]
-            self._sampled[slot] = sampled[i]
-            self._key_data[slot] = key_data[i]
-            if self._paged:
-                self._pages_tbl[slot] = a["table"]
-            t0 = a["t0"] if a["t0"] is not None else t_now
-            self._lat["ttft"].append(t_now - t0)
-            self._slots[slot] = _Slot(
-                req_id=rid,
-                real_len=L,
-                n_new=req.n_new,
-                n_emitted=1,
-                tokens=[int(tok0[i])],
-                logprobs=[float(lp0[i])],
-                comm_bytes=ctx.comm_bytes_per_participant(
-                    eng.config.n_kv_heads, eng.config.head_dim
-                ),
-                pages=a["pages"],
-                t_start=t0,
-                t_first=t_now,
-            )
-            if self.spec_k > 0:
-                # draft state sees the prompt plus the first emitted token
-                self._draft_state[slot] = self._drafter.begin(
-                    list(np.asarray(req.tokens)) + [int(tok0[i])]
-                )
+            pad = lambda a: np.concatenate(
+                [a, np.broadcast_to(a[:1], (Bp - B,) + a.shape[1:])]
+            ) if Bp > B else a  # padding rows replicate request 0
+            contributed = None
+            if contrib_rows:
+                contributed = jnp.asarray(pad(np.stack(contrib_rows)))
             if suffix:
-                self.stats["suffix_prefills"] += 1
-                self.stats["prefill_tokens"] += L - d
-            else:
-                self.stats["full_prefills"] += 1
-                self.stats["prefill_tokens"] += L
-            if self._prefix is not None and a["key_of"] is not None:
-                # publish this prompt's page run (entry refs protect the
-                # pages past this slot's retirement) — BEFORE any
-                # n_new==1 instant retirement frees the slot's own refs
-                self._prefix.insert(
-                    a["key_of"], L,
-                    [int(p) for p in
-                     a["table"][: paging.pages_for(L, self.page_size)]],
+                # gather tables: padding rows stay all-sentinel (clamped
+                # garbage gather; their compute is discarded anyway)
+                src = np.full((Bp, self._pp), self.num_pages, np.int32)
+                src[:B] = np.stack([a["src"] for a in adms])
+            # slot scatter of the real rows (padding rows get an
+            # out-of-range index and drop via scatter OOB semantics)
+            slot_idx = np.full(Bp, self.max_slots, np.int32)
+            slot_idx[:B] = slots
+            if self._paged:
+                dst = np.full((Bp, self._pp), self.num_pages, np.int32)
+                dst[:B] = np.stack([a["dst"] for a in adms])
+
+        with span("serve.admit.dispatch", rids=rids):
+            if suffix:
+                fn = eng._suffix_prefill_fn(Bp, Lp, C, n_rounds)
+                last, one = fn(
+                    eng._run_params(), self.cache, jnp.asarray(src),
+                    jnp.asarray(pad(tokens)), jnp.asarray(pad(real_len)),
+                    jnp.asarray(pad(write_lo)), jnp.asarray(pad(q_seg)),
+                    jnp.arange(C, dtype=jnp.int32), jnp.asarray(pad(kv_seg)),
+                    contributed,
                 )
-            if req.n_new == 1:
-                self._retire(slot)
-        self._slot_args = None  # slot set changed; re-upload wide arrays
+            else:
+                one = None
+                if self._prefill_caches is not None:
+                    one = self._prefill_caches.get(Bp)
+                if one is None:
+                    one = eng.model.init_cache(Bp, C, plan=self._plan)
+                    if self._prefill_caches is not None:
+                        self._prefill_caches[Bp] = one
+                fn = eng._prefill_fn(Bp, Lp, C, n_rounds, False, per_row=True)
+                last, one = fn(
+                    eng._run_params(), one, jnp.asarray(pad(tokens)),
+                    jnp.asarray(pad(real_len)), jnp.arange(Lp, dtype=jnp.int32),
+                    jnp.asarray(pad(q_seg)), jnp.arange(C, dtype=jnp.int32),
+                    jnp.asarray(pad(kv_seg)), contributed, None,
+                )
+            tok0, lp0 = self._admit_finish_fn()(
+                last, jnp.asarray(pad(temps)), jnp.asarray(pad(key_data)),
+                jnp.asarray(pad(sampled)),
+            )
+            if self._paged:
+                self.cache = self._slot_write_fn()(
+                    self.cache, one, jnp.asarray(slot_idx), jnp.asarray(dst)
+                )
+            else:
+                self.cache = self._slot_write_fn()(
+                    self.cache, one, jnp.asarray(slot_idx)
+                )
+
+        with span("serve.admit.sync", rids=rids):
+            tok0 = np.asarray(tok0)
+            lp0 = np.asarray(lp0)
+
+        with span("serve.admit.commit", rids=rids):
+            self.stats["prefill_padded_tokens"] += Bp * Lp
+            t_now = time.perf_counter()  # tok0 materialized ⇒ first token exists
+            for i, a in enumerate(adms):
+                slot, ctx, req, rid = slots[i], a["ctx"], a["req"], a["rid"]
+                L, d = a["L"], a["d"]
+                self._tok[slot] = int(tok0[i])
+                self._write_pos[slot] = L  # tok0's KV goes to position L next tick
+                self._fold[slot] = 1  # token m samples with fold_in(rng, m)
+                self._qseg[slot] = ctx.partition.publisher(ctx.config.publisher_index)
+                self._kvseg[slot] = kv_seg[i]
+                self._temps[slot] = temps[i]
+                self._sampled[slot] = sampled[i]
+                self._key_data[slot] = key_data[i]
+                if self._paged:
+                    self._pages_tbl[slot] = a["table"]
+                t0 = a["t0"] if a["t0"] is not None else t_now
+                self._lat["ttft"].append(t_now - t0)
+                self._slots[slot] = _Slot(
+                    req_id=rid,
+                    real_len=L,
+                    n_new=req.n_new,
+                    n_emitted=1,
+                    tokens=[int(tok0[i])],
+                    logprobs=[float(lp0[i])],
+                    comm_bytes=ctx.comm_bytes_per_participant(
+                        eng.config.n_kv_heads, eng.config.head_dim
+                    ),
+                    pages=a["pages"],
+                    t_start=t0,
+                    t_first=t_now,
+                )
+                if self.spec_k > 0:
+                    # draft state sees the prompt plus the first emitted token
+                    self._draft_state[slot] = self._drafter.begin(
+                        list(np.asarray(req.tokens)) + [int(tok0[i])]
+                    )
+                if suffix:
+                    self.stats["suffix_prefills"] += 1
+                    self.stats["prefill_tokens"] += L - d
+                else:
+                    self.stats["full_prefills"] += 1
+                    self.stats["prefill_tokens"] += L
+                if self._prefix is not None and a["key_of"] is not None:
+                    # publish this prompt's page run (entry refs protect the
+                    # pages past this slot's retirement) — BEFORE any
+                    # n_new==1 instant retirement frees the slot's own refs
+                    self._prefix.insert(
+                        a["key_of"], L,
+                        [int(p) for p in
+                         a["table"][: paging.pages_for(L, self.page_size)]],
+                    )
+                if req.n_new == 1:
+                    self._retire(slot)
+            self._slot_args = None  # slot set changed; re-upload wide arrays
 
     def _retire(self, slot: int) -> None:
         occ = self._slots[slot]
@@ -919,20 +934,21 @@ class ContinuousBatchingScheduler:
         if self._admit_fn is not None:
             return self._admit_fn
 
-        def finish(last, temps, key_data, sampled):
-            keys = jax.random.wrap_key_data(key_data)
-            greedy = jnp.argmax(last, axis=-1)
-            folded = jax.vmap(lambda k: jax.random.fold_in(k, 0))(keys)
-            cat = jax.vmap(
-                lambda k, l, t: jax.random.categorical(
-                    k, l.astype(jnp.float32) / t
-                )
-            )(folded, last, temps)
-            tok0 = jnp.where(sampled, cat, greedy)
-            return tok0, _token_logprob(last, tok0)
+        def admit_finish(last, temps, key_data, sampled):
+            with jax.named_scope(scopes.HEAD_SAMPLE):
+                keys = jax.random.wrap_key_data(key_data)
+                greedy = jnp.argmax(last, axis=-1)
+                folded = jax.vmap(lambda k: jax.random.fold_in(k, 0))(keys)
+                cat = jax.vmap(
+                    lambda k, l, t: jax.random.categorical(
+                        k, l.astype(jnp.float32) / t
+                    )
+                )(folded, last, temps)
+                tok0 = jnp.where(sampled, cat, greedy)
+                return tok0, _token_logprob(last, tok0)
 
         self._trace_guards["admit_finish"].charge(())
-        self._admit_fn = jax.jit(finish)
+        self._admit_fn = jax.jit(admit_finish)
         return self._admit_fn
 
     # -- the resident decode step -----------------------------------------------
@@ -952,18 +968,18 @@ class ContinuousBatchingScheduler:
             # page tables (sentinel entries — padding rows, shared
             # immutable prefix pages — drop at the scatter); recurrent
             # state still replaces whole slot rows
-            def write_paged(pool, batch, slots, dst_pages):
+            def slot_write(pool, batch, slots, dst_pages):
                 return self._constrain_cache(
                     T.paged_slot_write(pool, batch, dst_pages, slots)
                 )
 
             self._trace_guards["slot_write"].charge(())
             self._write_fn = jax.jit(
-                write_paged, donate_argnums=_donation_for_backend((0,))
+                slot_write, donate_argnums=_donation_for_backend((0,))
             )
             return self._write_fn
 
-        def write(pool, batch, slots):
+        def slot_write(pool, batch, slots):
             if scan_form:
                 # stacked leaves: (n_periods, B, ...) — batch axis 1
                 stacked = jax.tree.map(
@@ -983,7 +999,9 @@ class ContinuousBatchingScheduler:
             return self._constrain_cache(out)
 
         self._trace_guards["slot_write"].charge(())
-        self._write_fn = jax.jit(write, donate_argnums=_donation_for_backend((0,)))
+        self._write_fn = jax.jit(
+            slot_write, donate_argnums=_donation_for_backend((0,))
+        )
         return self._write_fn
 
     def _decode_proto(self):
@@ -1023,8 +1041,8 @@ class ContinuousBatchingScheduler:
         proto = self._decode_proto()
         kv_pos = jnp.arange(self._cap, dtype=jnp.int32)
 
-        def run(params, cache, tok, write_pos, fold, q_seg, kv_seg,
-                temps, sampled, key_data, pages=None):
+        def decode_step(params, cache, tok, write_pos, fold, q_seg, kv_seg,
+                        temps, sampled, key_data, pages=None):
             keys = jax.random.wrap_key_data(key_data)
 
             def body(carry, _):
@@ -1040,16 +1058,17 @@ class ContinuousBatchingScheduler:
                     backend=backend, dctx=dctx, mode=mode, plan=plan,
                     pages=pages,
                 )
-                last = logits[:, -1]
-                greedy = jnp.argmax(last, axis=-1)
-                folded = jax.vmap(jax.random.fold_in)(keys, fold)
-                cat = jax.vmap(
-                    lambda k, l, t: jax.random.categorical(
-                        k, l.astype(jnp.float32) / t
-                    )
-                )(folded, last, temps)
-                nxt = jnp.where(sampled, cat, greedy)
-                lp = _token_logprob(last, nxt)
+                with jax.named_scope(scopes.HEAD_SAMPLE):
+                    last = logits[:, -1]
+                    greedy = jnp.argmax(last, axis=-1)
+                    folded = jax.vmap(jax.random.fold_in)(keys, fold)
+                    cat = jax.vmap(
+                        lambda k, l, t: jax.random.categorical(
+                            k, l.astype(jnp.float32) / t
+                        )
+                    )(folded, last, temps)
+                    nxt = jnp.where(sampled, cat, greedy)
+                    lp = _token_logprob(last, nxt)
                 return (cache, nxt, wp + 1, fold + 1), (nxt, lp)
 
             (cache, _, _, _), (toks, lps) = jax.lax.scan(
@@ -1058,7 +1077,7 @@ class ContinuousBatchingScheduler:
             return toks, lps, self._constrain_cache(cache)  # (n_steps, S)
 
         self._trace_guards["decode_step"].charge(key)
-        fn = jax.jit(run, donate_argnums=_donation_for_backend((1,)))
+        fn = jax.jit(decode_step, donate_argnums=_donation_for_backend((1,)))
         self._step_fns[key] = fn
         return fn
 
@@ -1087,8 +1106,8 @@ class ContinuousBatchingScheduler:
         kv_pos = jnp.arange(self._cap, dtype=jnp.int32)
         offs = jnp.arange(self.spec_k + 1, dtype=jnp.int32)
 
-        def run(params, cache, tok, draft, write_pos, fold, q_seg, kv_seg,
-                temps, sampled, key_data, pages=None):
+        def verify_step(params, cache, tok, draft, write_pos, fold, q_seg,
+                        kv_seg, temps, sampled, key_data, pages=None):
             keys = jax.random.wrap_key_data(key_data)
             inp = jnp.concatenate([tok[:, None], draft], axis=1)  # (S, k+1)
             pos = write_pos[:, None] + offs[None, :]
@@ -1104,13 +1123,16 @@ class ContinuousBatchingScheduler:
                 backend=backend, dctx=dctx, mode=mode, plan=plan,
                 pages=pages,
             )  # (S, k+1, V) — every position's logits, not just the last
-            cand, lps, accept = _verify_candidates(
-                logits, draft, temps, keys, fold, sampled
-            )
+            with jax.named_scope(scopes.HEAD_SAMPLE):
+                cand, lps, accept = _verify_candidates(
+                    logits, draft, temps, keys, fold, sampled
+                )
             return cand, lps, accept, self._constrain_cache(cache)
 
         self._trace_guards["verify_step"].charge(self.spec_k)
-        self._verify = jax.jit(run, donate_argnums=_donation_for_backend((1,)))
+        self._verify = jax.jit(
+            verify_step, donate_argnums=_donation_for_backend((1,))
+        )
         return self._verify
 
     # -- the scheduler tick -----------------------------------------------------
@@ -1120,41 +1142,48 @@ class ContinuousBatchingScheduler:
         coalesced bucketed prefill per shape bucket), run one fused decode
         call over the pool, retire finished slots. Returns True if any
         decode work ran (False ⇒ idle: nothing active and nothing
-        admissible yet)."""
+        admissible yet). The whole call and each of its phases run under
+        the host spans of :mod:`repro.serving.spans`."""
+        with span("serve.step"):
+            return self._tick(now)
+
+    def _tick(self, now: Optional[float]) -> bool:
         free = [s for s, occ in enumerate(self._slots) if occ is None]
         batch: list = []
-        while self._queue and len(batch) < len(free):
-            rid, req, at = self._queue[0]
-            if at is not None and at > (now if now is not None else time.perf_counter()):
-                break
-            adm = self._prepare_admission(rid, req)
-            if adm is None:
-                # page pool exhausted (even after prefix-cache eviction) —
-                # the request stays at the head of the queue until
-                # retirements free pages; admission stays FIFO
-                break
-            self._queue.popleft()
-            # latency clock: a request "starts" at the later of submission
-            # and its scheduled arrival (trace replays submit up front)
-            t0 = self._submit_t.pop(rid, None)
-            if t0 is None:
-                t0 = time.perf_counter()
-            adm["t0"] = t0 if at is None else max(t0, at)
-            batch.append(adm)
-        if batch:
-            groups: dict = {}
+        groups: dict = {}
+        with span("serve.admit.plan") as plan:
+            while self._queue and len(batch) < len(free):
+                rid, req, at = self._queue[0]
+                if at is not None and at > (now if now is not None else time.perf_counter()):
+                    break
+                adm = self._prepare_admission(rid, req)
+                if adm is None:
+                    # page pool exhausted (even after prefix-cache eviction) —
+                    # the request stays at the head of the queue until
+                    # retirements free pages; admission stays FIFO
+                    break
+                self._queue.popleft()
+                # latency clock: a request "starts" at the later of submission
+                # and its scheduled arrival (trace replays submit up front)
+                t0 = self._submit_t.pop(rid, None)
+                if t0 is None:
+                    t0 = time.perf_counter()
+                adm["t0"] = t0 if at is None else max(t0, at)
+                batch.append(adm)
             for adm in batch:
                 # coalesce same-bucket admissions into one B>1 prefill —
                 # THE single admission path, every stack kind (per-row
                 # segment vectors drive attention visibility and the
                 # recurrence validity/reset masks alike). Prefix-cache
                 # hits bucket by SUFFIX length into their own groups.
-                Lp = self.engine._bucket_len(adm["L"] - adm["d"])
+                Lp = self.engine.bucket_len(adm["L"] - adm["d"])
                 groups.setdefault((Lp, adm["d"] > 0), []).append(adm)
-            for (Lp, suffix), adms in groups.items():
-                self._admit_group(
-                    [free.pop(0) for _ in adms], adms, Lp, suffix=suffix
-                )
+            plan.set_metadata(rids=[adm["rid"] for adm in batch])
+        for (Lp, suffix), adms in groups.items():
+            self._admit_group(
+                [free.pop(0) for _ in adms], adms, Lp, suffix=suffix
+            )
+        if batch:
             self.stats["peak_resident"] = max(
                 self.stats["peak_resident"], self.n_active
             )
@@ -1168,88 +1197,91 @@ class ContinuousBatchingScheduler:
             return False
 
         if self.spec_k > 0:
-            # host-side drafting: inactive rows keep zeros (their verify
-            # compute is discarded behind the PAD_SEGMENT mask anyway)
-            draft = np.zeros((self.max_slots, self.spec_k), np.int32)
-            for s, occ in enumerate(self._slots):
-                if occ is not None:
-                    draft[s] = self._drafter.draft(
-                        self._draft_state[s], self.spec_k
-                    )
+            with span("serve.draft"):
+                # host-side drafting: inactive rows keep zeros (their verify
+                # compute is discarded behind the PAD_SEGMENT mask anyway)
+                draft = np.zeros((self.max_slots, self.spec_k), np.int32)
+                for s, occ in enumerate(self._slots):
+                    if occ is not None:
+                        draft[s] = self._drafter.draft(
+                            self._draft_state[s], self.spec_k
+                        )
 
         with self._spmd_scope():
             fn = (
                 self._verify_fn() if self.spec_k > 0
                 else self._step_fn(self.steps_per_admit)
             )
-            if self._slot_args is None:
-                # wide / admission-rate inputs: re-uploaded only when the
-                # slot set changed, not every tick
-                self._slot_args = (
-                    jnp.asarray(self._qseg), jnp.asarray(self._kvseg),
-                    jnp.asarray(self._temps), jnp.asarray(self._sampled),
-                    jnp.asarray(self._key_data),
-                ) + (
-                    (jnp.asarray(self._pages_tbl),) if self._paged else ()
+            with span("serve.decode.upload"):
+                if self._slot_args is None:
+                    # wide / admission-rate inputs: re-uploaded only when the
+                    # slot set changed, not every tick
+                    self._slot_args = (
+                        jnp.asarray(self._qseg), jnp.asarray(self._kvseg),
+                        jnp.asarray(self._temps), jnp.asarray(self._sampled),
+                        jnp.asarray(self._key_data),
+                    ) + (
+                        (jnp.asarray(self._pages_tbl),) if self._paged else ()
+                    )
+                per_tick = [jnp.asarray(self._tok)]
+                if self.spec_k > 0:
+                    per_tick.append(jnp.asarray(draft))
+                per_tick += [jnp.asarray(self._write_pos), jnp.asarray(self._fold)]
+            with span("serve.decode.dispatch"):
+                out = fn(
+                    self.engine._run_params(), self.cache, *per_tick,
+                    *self._slot_args,
                 )
-            q_seg, kv_seg, temps, sampled, key_data = self._slot_args[:5]
-            if self.spec_k > 0:
-                cand, lps, acc, self.cache = fn(
-                    self.engine._run_params(), self.cache,
-                    jnp.asarray(self._tok), jnp.asarray(draft),
-                    jnp.asarray(self._write_pos), jnp.asarray(self._fold),
-                    q_seg, kv_seg, temps, sampled, key_data,
-                    *self._slot_args[5:],
-                )
-            else:
-                toks, lps, self.cache = fn(
-                    self.engine._run_params(), self.cache,
-                    jnp.asarray(self._tok), jnp.asarray(self._write_pos),
-                    jnp.asarray(self._fold), q_seg, kv_seg, temps, sampled,
-                    key_data, *self._slot_args[5:],
-                )
+            self.cache = out[-1]
 
+        retired = []
         if self.spec_k > 0:
-            # ragged frontier advance: slot s moves by accept+1 (its
-            # accepted drafts plus the correction/bonus token), its
-            # neighbor by whatever IT accepted — all from ONE verify call
-            cand = np.asarray(cand)  # (S, k+1)
-            lps = np.asarray(lps)
-            acc = np.asarray(acc)  # (S,) accept lengths in [0, k]
-            self.stats["verify_ticks"] += 1
+            with span("serve.decode.sync"):
+                cand, lps, acc = (np.asarray(a) for a in out[:3])
+            with span("serve.decode.commit") as commit:
+                # ragged frontier advance: slot s moves by accept+1 (its
+                # accepted drafts plus the correction/bonus token), its
+                # neighbor by whatever IT accepted — all from ONE verify call
+                # (cand (S, k+1); acc (S,) accept lengths in [0, k])
+                self.stats["verify_ticks"] += 1
+                for s, occ in enumerate(self._slots):
+                    if occ is None:
+                        continue
+                    a = int(acc[s])
+                    take = min(a + 1, occ.n_new - occ.n_emitted)
+                    occ.tokens.extend(int(t) for t in cand[s, :take])
+                    occ.logprobs.extend(float(l) for l in lps[s, :take])
+                    occ.n_emitted += take
+                    self._tok[s] = int(cand[s, take - 1])
+                    self._write_pos[s] += take
+                    self._fold[s] += take
+                    self._drafter.update(self._draft_state[s], cand[s, :take])
+                    self.stats["spec_drafted"] += self.spec_k
+                    self.stats["spec_accepted"] += min(a, take)
+                    if occ.n_emitted >= occ.n_new:
+                        retired.append(occ.req_id)
+                        self._retire(s)
+                commit.set_metadata(rids=retired)
+            return True
+
+        with span("serve.decode.sync"):
+            toks, lps = np.asarray(out[0]), np.asarray(out[1])
+        with span("serve.decode.commit") as commit:
+            k = self.steps_per_admit
             for s, occ in enumerate(self._slots):
                 if occ is None:
                     continue
-                a = int(acc[s])
-                take = min(a + 1, occ.n_new - occ.n_emitted)
-                occ.tokens.extend(int(t) for t in cand[s, :take])
-                occ.logprobs.extend(float(l) for l in lps[s, :take])
+                take = min(k, occ.n_new - occ.n_emitted)
+                occ.tokens.extend(int(t) for t in toks[:take, s])
+                occ.logprobs.extend(float(l) for l in lps[:take, s])
                 occ.n_emitted += take
-                self._tok[s] = int(cand[s, take - 1])
-                self._write_pos[s] += take
-                self._fold[s] += take
-                self._drafter.update(self._draft_state[s], cand[s, :take])
-                self.stats["spec_drafted"] += self.spec_k
-                self.stats["spec_accepted"] += min(a, take)
+                self._tok[s] = int(toks[-1, s])
+                self._write_pos[s] += k
+                self._fold[s] += k
                 if occ.n_emitted >= occ.n_new:
+                    retired.append(occ.req_id)
                     self._retire(s)
-            return True
-
-        toks = np.asarray(toks)
-        lps = np.asarray(lps)
-        k = self.steps_per_admit
-        for s, occ in enumerate(self._slots):
-            if occ is None:
-                continue
-            take = min(k, occ.n_new - occ.n_emitted)
-            occ.tokens.extend(int(t) for t in toks[:take, s])
-            occ.logprobs.extend(float(l) for l in lps[:take, s])
-            occ.n_emitted += take
-            self._tok[s] = int(toks[-1, s])
-            self._write_pos[s] += k
-            self._fold[s] += k
-            if occ.n_emitted >= occ.n_new:
-                self._retire(s)
+            commit.set_metadata(rids=retired)
         return True
 
     # -- drive to completion ----------------------------------------------------

@@ -1,4 +1,4 @@
-"""Shape-bucket policy edges: properties of _next_pow2 / _bucket_len /
+"""Shape-bucket policy edges: properties of _next_pow2 / bucket_len /
 _bucket_new at boundaries (1, exact powers of two, power+1), and the
 segment ``-1`` padding sentinel surviving a full generate round-trip at
 those boundaries (bucketed-prefill padding must never leak into real
@@ -74,7 +74,7 @@ def test_bucket_len_and_new_policy_every_stack(n):
     for kind in STACKS:
         eng = _eng(kind)
         assert eng._bucket_L_ok, kind
-        assert eng._bucket_len(n) == _next_pow2(n), kind
+        assert eng.bucket_len(n) == _next_pow2(n), kind
         assert eng._bucket_new(n) == _next_pow2(n), kind
 
 
@@ -82,7 +82,7 @@ def test_bucket_len_and_new_policy_every_stack(n):
 @settings(max_examples=20, deadline=None)  # first example builds an engine
 def test_bucket_none_is_identity(n):
     """bucket='none' opts out of padding entirely, both dims."""
-    assert _eng("none")._bucket_len(n) == n
+    assert _eng("none").bucket_len(n) == n
     assert _eng("none")._bucket_new(n) == n
 
 
