@@ -377,6 +377,53 @@ class AttnSpec:
         )
 
 
+# Grouped-query attention. Query head ``h = kv·g + j`` (``g = nq // nkv``)
+# reads kv head ``kv``: the bodies below view q as (B, Lq, nkv, g, dh) and
+# contract it against K/V at their own nkv heads, so no K/V copy at nq
+# heads exists (g = 1 is plain multi-head attention). Every f32 contraction
+# asks for HIGHEST precision: a grouped dot has g·Lq rows, which XLA on a
+# TPU would otherwise round to a single bf16 pass.
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def grouped_query(q: jnp.ndarray, nkv: int, scale: float) -> jnp.ndarray:
+    """(B, Lq, nq, dh) → f32 (B, Lq, nkv, g, dh), scaled; head ``kv·g + j``
+    lands at ``[:, :, kv, j]``."""
+    B, Lq, nq, dh = q.shape
+    return (q.astype(jnp.float32) * scale).reshape(B, Lq, nkv, nq // nkv, dh)
+
+
+def grouped_scores(
+    qg: jnp.ndarray,  # (B, Lq, nkv, g, dh) f32, from grouped_query
+    k: jnp.ndarray,  # (B, Lk, nkv, dh)
+    mask: jnp.ndarray,  # (Bm, Lq, Lk)
+    soft_cap: Optional[float] = None,
+) -> jnp.ndarray:
+    """f32 logits (B, nkv, g, Lq, Lk), soft-capped, invisible columns at
+    ``NEG_INF``."""
+    s = jnp.einsum(
+        "bqkgd,btkd->bkgqt", qg, k.astype(jnp.float32), precision=_HIGHEST
+    )
+    if soft_cap:
+        s = jnp.tanh(s / soft_cap) * soft_cap
+    return jnp.where(mask[:, None, None], s, NEG_INF)
+
+
+def grouped_probs(s: jnp.ndarray, m: jnp.ndarray, mask: jnp.ndarray) -> jnp.ndarray:
+    """Softmax numerators ``exp(s - m)`` of :func:`grouped_scores` logits
+    against the row max ``m`` (B, nkv, g, Lq), re-masked to exact zeros (a
+    fully-masked row has ``m = NEG_INF`` and would read ``exp(0)``)."""
+    return jnp.where(mask[:, None, None], jnp.exp(s - m[..., None]), 0.0)
+
+
+def grouped_values(p: jnp.ndarray, v: jnp.ndarray) -> jnp.ndarray:
+    """``p`` (B, nkv, g, Lq, Lk) · ``v`` (B, Lk, nkv, dh) → f32
+    (B, Lq, nkv, g, dh)."""
+    return jnp.einsum(
+        "bkgqt,btkd->bqkgd", p, v.astype(jnp.float32), precision=_HIGHEST
+    )
+
+
 def masked_attention(
     q: jnp.ndarray,  # (B, Lq, nq, dh)
     k: jnp.ndarray,  # (B, Lk, nkv, dh)
@@ -388,7 +435,12 @@ def masked_attention(
     return_stats: bool = False,
     return_probs: bool = False,
 ) -> jnp.ndarray | tuple[jnp.ndarray, ...]:
-    """The ONE masked-softmax attention body (GQA-aware, f32 accumulation).
+    """The ONE masked-softmax attention body (f32 accumulation).
+
+    GQA is grouped, not broadcast: query head ``h = kv·g + j`` is contracted
+    against kv head ``kv`` of ``k``/``v`` at their own ``nkv`` heads
+    (:func:`grouped_scores`, :func:`grouped_values`); every output below is
+    in the per-query-head layout, heads in that order.
 
     With ``return_stats`` it returns the partial-softmax statistics
     ``(m, l, acc)`` — ``m`` (B, nq, Lq) running max, ``l`` row mass, ``acc``
@@ -402,26 +454,19 @@ def masked_attention(
     Fully-masked rows yield zero output (l = 0 guarded), never NaN.
     """
     B, Lq, nq, dh = q.shape
-    nkv = k.shape[2]
-    g = nq // nkv
     scale = sm_scale if sm_scale is not None else dh**-0.5
     if mask.ndim == 2:
         mask = mask[None]
-    qf = q.astype(jnp.float32) * scale
-    kf = jnp.repeat(k.astype(jnp.float32), g, axis=2)
-    vf = jnp.repeat(v.astype(jnp.float32), g, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", qf, kf)
-    if soft_cap:
-        s = jnp.tanh(s / soft_cap) * soft_cap
-    s = jnp.where(mask[:, None], s, NEG_INF)
-    m = jnp.max(s, axis=-1)  # (B, nq, Lq)
-    p = jnp.exp(s - m[..., None])
-    p = jnp.where(mask[:, None], p, 0.0)
-    l = jnp.sum(p, axis=-1)
-    acc = jnp.einsum("bhqk,bkhd->bqhd", p, vf)
+    qg = grouped_query(q, k.shape[2], scale)
+    s = grouped_scores(qg, k, mask, soft_cap)  # (B, nkv, g, Lq, Lk)
+    m = jnp.max(s, axis=-1)
+    p = grouped_probs(s, m, mask)
+    l = jnp.sum(p, axis=-1).reshape(B, nq, Lq)
+    m = m.reshape(B, nq, Lq)
+    acc = grouped_values(p, v).reshape(B, Lq, nq, dh)
     if return_stats:
         if return_probs:
-            return m, l, acc, p
+            return m, l, acc, p.reshape(B, nq, Lq, -1)
         return m, l, acc
     out = acc / jnp.maximum(l, 1e-20).transpose(0, 2, 1)[..., None]
     return out.astype(q.dtype)

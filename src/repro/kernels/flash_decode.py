@@ -34,7 +34,7 @@ int8/fp8 codes saved. This module fuses the whole read side:
   sentinel-page columns forced to ``PAD_POS``/``KERNEL_PAD_SEGMENT``
   *before* any visibility decision (visibility is never decided by page
   identity), ``window``/``soft_cap``/GQA (``q`` head ``h`` reads kv head
-  ``h // g`` — exactly ``jnp.repeat`` semantics), ``contributed``
+  ``h // g`` — the head order of ``kernels.core.grouped_query``), ``contributed``
   sparse-exchange thinning and ``publisher_lo``. ``S > 1`` rows are the
   multi-query verify form, so speculative decode rides the same kernel.
 
@@ -103,8 +103,8 @@ def _block_attend(q, k, v, mask, soft_cap):
 def _prep(q, pk, pages, q_pos, kv_pos, q_seg, kv_seg, contributed, local_only):
     """Shared operand pre-pass, in the tile layout both implementations
     read. Queries are grouped by kv head: ``qg`` (B, nkv, g·S, dh) with
-    row ``j·S + s`` = q head ``h·g + j`` at query row ``s`` (exactly
-    ``jnp.repeat`` GQA semantics). Query-side vectors become per-group
+    row ``j·S + s`` = q head ``h·g + j`` at query row ``s`` (the head
+    order of ``kernels.core.grouped_query``). Query-side vectors become per-group
     columns ``(B, g·S, 1)``; key-side vectors become per-page rows
     ``(B, P', 1, ps)``, with sentinel-page columns (table entries >=
     num_pages) forced behind the ``PAD_POS``/``KERNEL_PAD_SEGMENT``

@@ -14,6 +14,13 @@ Backends:
 All wrappers share the FedAttn masking vocabulary: global positions,
 participant segment ids, `local_only` (Phase-I local attention) and
 `contributed` (sparse KV exchange at sync layers).
+
+Grouped-query attention (nq query heads over nkv KV heads, g = nq // nkv)
+is grouped in the xla/ref bodies, never broadcast: query head
+h = kv·g + j is contracted against kv head `kv` at K/V's own nkv heads
+(kernels.core.grouped_scores / grouped_values), so no K/V copy at nq
+heads is ever built. The Pallas kernels group the same way (head h reads
+kv head h // g).
 """
 from __future__ import annotations
 
@@ -138,7 +145,6 @@ def _chunked_attention(
     """
     B, Lq, nq, dh = q.shape
     _, Lk, nkv, _ = k.shape
-    g = nq // nkv
     scale = sm_scale if sm_scale is not None else dh**-0.5
 
     spec = _core.AttnSpec(
@@ -159,41 +165,39 @@ def _chunked_attention(
     sl = lambda a, i: jax.lax.dynamic_slice_in_dim(a, i * chunk, chunk, axis=1)
     fetch = lambda i: (sl(k, i), sl(v, i))
     return _online_attention(
-        q, fetch, spec, chunk, n_chunks, g=g, soft_cap=soft_cap, scale=scale
+        q, fetch, spec, chunk, n_chunks, nkv=nkv, soft_cap=soft_cap, scale=scale
     )
 
 
-def _online_attention(q, fetch, spec, chunk, n_chunks, *, g, soft_cap, scale):
+def _online_attention(q, fetch, spec, chunk, n_chunks, *, nkv, soft_cap, scale):
     """Online-softmax (flash-style) accumulation over KV chunks. ``fetch(i)``
     supplies chunk ``i``'s (kc, vc) — a dynamic slice of a dense cache or a
     page-group gather from a paged pool; the math is identical, so paged and
-    dense attention agree bitwise wherever their masks agree."""
+    dense attention agree bitwise wherever their masks agree. Each chunk
+    runs the core's grouped-query contraction (``kernels.core.
+    grouped_scores``/``grouped_values``): the carry stays grouped,
+    (B, nkv, g, Lq) stats and (B, Lq, nkv, g, dh) values, until the end."""
     B, Lq, nq, dh = q.shape
-    qf = q.astype(jnp.float32) * scale
+    g = nq // nkv
+    qg = _core.grouped_query(q, nkv, scale)
 
     def body(carry, i):
-        m, l, acc = carry  # (B,nq,Lq), (B,nq,Lq), (B,Lq,nq,dh)
+        m, l, acc = carry  # (B,nkv,g,Lq), (B,nkv,g,Lq), (B,Lq,nkv,g,dh)
         kc, vc = fetch(i)
-        kcf = jnp.repeat(kc.astype(jnp.float32), g, axis=2)
-        vcf = jnp.repeat(vc.astype(jnp.float32), g, axis=2)
-        s = jnp.einsum("bqhd,bkhd->bhqk", qf, kcf)  # (B,nq,Lq,chunk)
-        if soft_cap:
-            s = jnp.tanh(s / soft_cap) * soft_cap
         mask = spec.chunk_kv(i * chunk, chunk).mask()  # (Bm, Lq, chunk)
-        s = jnp.where(mask[:, None], s, NEG_INF)
+        s = _core.grouped_scores(qg, kc, mask, soft_cap)  # (B,nkv,g,Lq,chunk)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        p = jnp.where(mask[:, None], p, 0.0)
+        p = _core.grouped_probs(s, m_new, mask)
         corr = jnp.exp(m - m_new)
         l_new = l * corr + jnp.sum(p, axis=-1)
-        acc = acc * corr.transpose(0, 2, 1)[..., None] + jnp.einsum(
-            "bhqk,bkhd->bqhd", p, vcf
+        acc = acc * jnp.moveaxis(corr, -1, 1)[..., None] + _core.grouped_values(
+            p, vc
         )
         return (m_new, l_new, acc), None
 
-    m0 = jnp.full((B, nq, Lq), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((B, nq, Lq), jnp.float32)
-    acc0 = jnp.zeros((B, Lq, nq, dh), jnp.float32)
+    m0 = jnp.full((B, nkv, g, Lq), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((B, nkv, g, Lq), jnp.float32)
+    acc0 = jnp.zeros((B, Lq, nkv, g, dh), jnp.float32)
     from repro.kernels.probe import probe_mode
 
     if probe_mode():
@@ -205,8 +209,8 @@ def _online_attention(q, fetch, spec, chunk, n_chunks, *, g, soft_cap, scale):
     else:
         (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0), jnp.arange(n_chunks))
     l = jnp.maximum(l, 1e-20)
-    out = acc / l.transpose(0, 2, 1)[..., None]
-    return out.astype(q.dtype)
+    out = acc / jnp.moveaxis(l, -1, 1)[..., None]
+    return out.reshape(B, Lq, nq, dh).astype(q.dtype)
 
 
 def attention_masked(
@@ -340,7 +344,6 @@ def _chunked_paged_attention(
     B, Lq, nq, dh = q.shape
     N, ps, nkv = pk.shape[0], pk.shape[1], pk.shape[2]
     Pp = pages.shape[1]
-    g = nq // nkv
     scale = sm_scale if sm_scale is not None else dh**-0.5
 
     # clamp to the live pool extent FIRST — a short pool must never be
@@ -378,7 +381,7 @@ def _chunked_paged_attention(
         )
 
     return _online_attention(
-        q, fetch, spec, chunk, n_groups, g=g, soft_cap=soft_cap, scale=scale
+        q, fetch, spec, chunk, n_groups, nkv=nkv, soft_cap=soft_cap, scale=scale
     )
 
 
