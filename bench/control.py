@@ -46,7 +46,7 @@ def readings(workload: str, seeds: list[int], seconds: float, *, root: Path = ha
     for i, seed in enumerate(seeds):
         w_key, rng = model.seeds(seed)
         weights = model.make_weights(dm, w_key)
-        engine.set_params(model.program_params(weights))
+        engine.set_params(model.program_params(weights, dm))
         sched = harness.new_scheduler(engine, cell)
         traffic = gen.Traffic(cell, rng, dm.vocab, seconds)
         if i == 0:

@@ -6,16 +6,23 @@ FedAttn mask leaves visible, and the LM head once per sampled position.
 Not counted: bucket padding, pool pages and tiles that the mask hides,
 coasting sub-steps of finished slots, norms, RoPE and softmax.
 
-Visibility (``kernels/core.tile_visibility`` in the program, written out
-here): causal everywhere; a local layer's query sees keys of its own
-participant only, a sync layer's query sees every earlier key. A prompt is
-split over N participants by the contiguous partition (the remainder goes
-to the last participants); generated tokens belong to the publisher, the
-last participant.
+What a layer costs is the architecture module's (``bench/model.py``):
+``weight_flops`` per token and ``pair_flops`` per visible pair, layer by
+layer. A module whose mask is not the plain FedAttn one gives its own
+``prefill_flops`` and ``decode_flops``, which take the place of those here.
+
+Visibility (``kernels/core.tile_visibility`` in the program,
+``reference.visible`` in the benchmark): causal everywhere; a local
+layer's query sees keys of its own participant only, a sync layer's query
+sees every earlier key. A prompt is split over N participants by the
+contiguous partition (the remainder goes to the last participants);
+generated tokens belong to the publisher, the last participant.
 """
 from __future__ import annotations
 
-from bench.model import Dims
+import functools
+
+from bench.model import Dims, arch
 
 
 def segment_sizes(L: int, n: int) -> list[int]:
@@ -41,29 +48,41 @@ def decode_pairs(L: int, n: int, m_lo: int, m_hi: int) -> tuple[int, int]:
     return k * pub + s_m, k * L + s_m
 
 
-def _per_token(dm: Dims) -> int:
-    q, kv = dm.n_q * dm.d_head, dm.n_kv * dm.d_head
-    return 2 * (dm.d * q + 2 * dm.d * kv + q * dm.d + 3 * dm.d * dm.d_ff)
+@functools.lru_cache(maxsize=None)
+def _terms(dm: Dims) -> tuple[int, int, int]:
+    """(weight FLOPs per token over all layers, attention FLOPs per visible
+    pair over the local layers, over the sync layers), from the
+    architecture module's per-layer counts."""
+    a = arch(dm.model_type)
+    per_pair = [(a.pair_flops(dm, i), dm.is_sync(i)) for i in range(dm.n_layers)]
+    return (sum(a.weight_flops(dm, i) for i in range(dm.n_layers)),
+            sum(f for f, sync in per_pair if not sync),
+            sum(f for f, sync in per_pair if sync))
 
 
-def _attn(dm: Dims, local_pairs: int, sync_pairs: int) -> int:
-    n_sync = dm.n_layers // dm.sync_interval
-    per_pair = 4 * dm.n_q * dm.d_head  # q.k and p.v
-    return per_pair * ((dm.n_layers - n_sync) * local_pairs + n_sync * sync_pairs)
+def _head(dm: Dims) -> int:
+    return 2 * dm.d * dm.vocab
 
 
 def prefill_flops(dm: Dims, L: int) -> int:
     """Prefill of one prompt, with its first sampled position."""
-    return (dm.n_layers * L * _per_token(dm)
-            + _attn(dm, *prefill_pairs(L, dm.participants))
-            + 2 * dm.d * dm.vocab)
+    own = getattr(arch(dm.model_type), "prefill_flops", None)
+    if own is not None:
+        return own(dm, L)
+    weights, local, sync = _terms(dm)
+    local_pairs, sync_pairs = prefill_pairs(L, dm.participants)
+    return L * weights + local * local_pairs + sync * sync_pairs + _head(dm)
 
 
 def decode_flops(dm: Dims, L: int, m_lo: int, m_hi: int) -> int:
     """The decode steps producing generated tokens m_lo < m <= m_hi (token
     0 comes from the prefill)."""
+    own = getattr(arch(dm.model_type), "decode_flops", None)
+    if own is not None:
+        return own(dm, L, m_lo, m_hi)
     k = m_hi - m_lo
     if k <= 0:
         return 0
-    return (k * (dm.n_layers * _per_token(dm) + 2 * dm.d * dm.vocab)
-            + _attn(dm, *decode_pairs(L, dm.participants, m_lo, m_hi)))
+    weights, local, sync = _terms(dm)
+    local_pairs, sync_pairs = decode_pairs(L, dm.participants, m_lo, m_hi)
+    return k * (weights + _head(dm)) + local * local_pairs + sync * sync_pairs

@@ -1,11 +1,16 @@
 """One run of one cell: build, warm up, measure, check, report.
 
 The cell's entry in ``BENCHMARK.json`` names its configuration
-(``bench/configs/<config>.json``); its own file ``bench/cells/<cell>.json``
-holds the traffic's parameters and the deployment's knobs, and names the
-arrival process, whose generator is ``bench/traffic/<arrivals>.py``. Each
-metric is read by ``bench/metrics/<metric>.py`` from the run's
-:class:`Record`. Nothing here names a cell, a configuration or a metric.
+(``bench/configs/<config>.json``), whose ``model_type`` names its
+architecture (``bench/arch/<model_type>.py``); its own file
+``bench/cells/<cell>.json`` holds the traffic's parameters and the
+deployment's knobs, and names the arrival process, whose generator is
+``bench/traffic/<arrivals>.py``. Each metric is read by
+``bench/metrics/<metric>.py`` from the run's :class:`Record`: the window's
+steps and requests, the change of the scheduler's counters over the
+window, and in a ``--trace 1`` run the window's trace reduced to the
+program's layers (``bench/layers.py``). Nothing here names a cell, a
+configuration, an architecture or a metric.
 
 The window drives ``ContinuousBatchingScheduler.step()`` over a
 ``FedAttnEngine``: the harness submits each request when its client sends
@@ -23,6 +28,7 @@ scheduler's public surface alone (``submit``, ``step``, ``n_active``,
 """
 from __future__ import annotations
 
+import gc
 import importlib
 import importlib.util
 import json
@@ -30,21 +36,18 @@ import math
 import sys
 import time
 from collections import deque
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Optional
 
 import numpy as np
 
-from bench import check, flops, model
+from bench import check, flops, layers, model
+from bench.model import Refused
 from bench.workload import prompt
 
 ROOT = Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
-
-
-class Refused(Exception):
-    """The run cannot be made here (no chip, too few chips, bad cell)."""
 
 
 @dataclass
@@ -84,7 +87,9 @@ class Record:
     ticks: list  # the window's steps
     reqs: list  # every request of the run
     peak: dict  # bench/peaks.json entry of this device
-    trace: Optional[object] = None  # bench.trace.Summary of a --trace 1 run
+    # the scheduler's counters (``stats``): their change over the window
+    counters: dict = field(default_factory=dict)
+    trace: Optional[object] = None  # bench.layers.Summary of a --trace 1 run
 
     @property
     def window_s(self) -> float:
@@ -277,7 +282,7 @@ def warm_up(sched, engine, sizes, width: int, rng, vocab: int) -> int:
 
     by_bucket: dict = {}
     for L in sorted({L for L, _ in sizes}):
-        by_bucket.setdefault(engine._bucket_len(L), []).append(L)
+        by_bucket.setdefault(engine.bucket_len(L), []).append(L)
     widths = [1 << i for i in range(int(math.log2(width)) + 1)]
     n = 0
     for bucket in sorted(by_bucket):
@@ -340,6 +345,7 @@ class Served:
     t0: float
     t1: float
     compiles: dict  # traces and compiles inside the window
+    counters: dict  # the scheduler's counters: their change over the window
 
 
 def serve(sched, traffic, cell: dict, seconds: float, *,
@@ -359,6 +365,7 @@ def serve(sched, traffic, cell: dict, seconds: float, *,
     counter = CompileCounter()
     if trace_dir is not None:
         jax.profiler.start_trace(trace_dir)
+    at_start = dict(sched.stats)
     counter.on = True
     drv.phase = "window"
     t0 = time.perf_counter()
@@ -366,22 +373,55 @@ def serve(sched, traffic, cell: dict, seconds: float, *,
         drv.run_until(t0 + seconds)
         t1 = max([t0] + [k.t_end for k in drv.ticks if k.phase == "window"])
     counter.on = False
+    at_end = dict(sched.stats)
     if trace_dir is not None:
         jax.profiler.stop_trace()
     counter.close()
-    return Served(drv, t0, t1, dict(counter.count))
+    return Served(drv, t0, t1, dict(counter.count),
+                  {k: at_end[k] - at_start[k] for k in at_end})
 
 
-def run(workload: str, seed: int, seconds: float, trace: bool, *,
-        t_process: float, root: Path = ROOT, require_tpu: bool = True,
-        fault=None, log=print) -> dict:
-    """Make one run and return the result line's object."""
+def _read_trace(trace_dir: str, keep: Optional[str]):
+    """The layered summary of the window's trace (None without one that
+    holds a device plane); the ``.xplane.pb`` is copied to ``keep`` if
+    given, and the directory removed."""
+    import glob
+    import shutil
+
+    files = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    summary = None
+    if files:
+        if keep:
+            shutil.copy(files[0], keep)
+        summary = layers.summarize(layers.read(files[0]))
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return summary
+
+
+@dataclass
+class Measured:
+    """One served window of a cell, and what its check needs."""
+
+    bench: dict
+    cell: dict
+    rec: Record
+    weights: dict
+    devs: list
+    memory_peak_bytes: Optional[int]
+    compiles: dict  # traces and compiles inside the window
+
+
+def measure(workload: str, seed: int, seconds: float, trace: bool, *,
+            t_process: float, root: Path = ROOT, require_tpu: bool = True,
+            fault=None, keep: Optional[str] = None, log=print) -> Measured:
+    """Build the cell, warm it up and serve its window; with ``trace`` the
+    window is traced and reduced (``keep`` copies the trace there)."""
     bench, entry, cell, cfg = load_cell(workload, root)
     devs = devices(entry["chips"], require_tpu)
     dev = devs[0]
     log(f"device: {dev.platform} {dev.device_kind} x{len(devs)}")
 
-    log(f"compile cache: {enable_cache()}")
+    log(f"compile cache: {enable_cache()}; at {time.perf_counter() - t_process:.1f} s")
     peaks = model.load_json(BENCH / "peaks.json")
     if dev.device_kind not in peaks and require_tpu:
         raise Refused(f"no peaks for device kind {dev.device_kind!r} in bench/peaks.json")
@@ -390,13 +430,15 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     w_key, rng = model.seeds(seed)
     dm, engine = new_engine(cell, cfg)
     weights = model.make_weights(dm, w_key)
-    engine.set_params(model.program_params(weights))
+    engine.set_params(model.program_params(weights, dm))
     sched = new_scheduler(engine, cell)
+    log(f"set-up: weights and engine built at {time.perf_counter() - t_process:.1f} s")
     gen = importlib.import_module(f"bench.traffic.{cell['arrivals']}")
     traffic = gen.Traffic(cell, rng, dm.vocab, seconds)
     warm_rng = np.random.default_rng([seed, 1])
     groups = warm_up(sched, engine, traffic.sizes, cell["warm_width"], warm_rng, dm.vocab)
-    log(f"warm-up: {groups} admission groups; executables {sched.compile_counts}")
+    log(f"warm-up: {groups} admission groups; executables {sched.compile_counts}; "
+        f"done at {time.perf_counter() - t_process:.1f} s")
 
     tracer = None
     if trace:
@@ -414,6 +456,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     rec = Record(
         dm=dm, steps_per_admit=cell["steps_per_admit"], max_slots=cell["max_slots"],
         setup_s=setup_s, t0=t0, t1=t1, ticks=window_ticks, reqs=drv.reqs, peak=peak,
+        counters=sv.counters,
     )
     wreqs = rec.window_reqs()
     late = [r.t_submit - r.due for r in drv.reqs if r.phase == "window"]
@@ -434,51 +477,58 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     log(f"compiles inside the window: {sum(sv.compiles.values())} {sv.compiles}")
     log(f"peak device bytes: {mem_peak}")
 
-    summary = None
     if tracer is not None:
-        import glob
-        import shutil
+        t_read = time.perf_counter()
+        rec.trace = _read_trace(tracer, keep)
+        log(f"trace: reduced in {time.perf_counter() - t_read:.1f} s")
+    sched.cache = None  # free the pool before the reference runs
+    return Measured(bench, cell, rec, weights, devs, mem_peak, sv.compiles)
 
-        from bench import trace as trace_mod
 
-        files = glob.glob(f"{tracer}/plugins/profile/*/*.xplane.pb")
-        if files:
-            summary = trace_mod.summarize(trace_mod.read(files[0]))
-        shutil.rmtree(tracer, ignore_errors=True)
-        rec.trace = summary
+def report(m: Measured, workload: str, seed: int, trace: bool, log=print) -> dict:
+    """The result line's object of a measured window: its metrics, then
+    the check of the served requests against the reference."""
+    rec, summary = m.rec, m.rec.trace
 
     key = "per_layer" if trace else "end_to_end"
     metrics = {}
-    for m in metrics_for(bench, workload, key):
-        v = metric_reader(m["name"])(rec)
+    for spec in metrics_for(m.bench, workload, key):
+        v = metric_reader(spec["name"])(rec)
         if v is not None:
-            metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+            metrics[spec["name"]] = {"value": v, "unit": spec["unit"]}
 
     # --- correctness: the served requests against the reference ---
-    picked = check.sample(wreqs, np.random.default_rng([seed, 2]), cell["check"]["requests"])
-    sched.cache = None  # free the pool before the reference runs
-    del drv, sched, engine, traffic
-    import gc
-
+    wreqs = rec.window_reqs()
+    picked = check.sample(wreqs, np.random.default_rng([seed, 2]), m.cell["check"]["requests"])
     gc.collect()
     t_ref = time.perf_counter()
-    read = check.readings(weights, dm, picked)
-    ok, checks = check.verdict(read, cell["check"]["limits"])
+    read = check.readings(m.weights, rec.dm, picked)
+    ok, checks = check.verdict(read, m.cell["check"]["limits"])
     log(f"reference: {read['requests']} requests, {read['tokens']} served tokens "
         f"compared in {time.perf_counter() - t_ref:.1f} s")
 
-    device = {"platform": dev.platform, "kind": dev.device_kind, "count": len(devs),
-              "memory_peak_bytes": mem_peak}
+    dev = m.devs[0]
+    device = {"platform": dev.platform, "kind": dev.device_kind, "count": len(m.devs),
+              "memory_peak_bytes": m.memory_peak_bytes}
     if summary is not None:
-        device["busy_s"] = summary.busy_s
-        device["window_s"] = summary.window_s
+        device["busy_s"] = summary.base.busy_s
+        device["window_s"] = summary.base.window_s
     out = {"correct": bool(ok), "attempted": len(wreqs), "failed": 0,
            "metrics": metrics, "device": device}
     if summary is not None:
         out["breakdown"] = {"device_ops": summary.device_ops,
-                            "idle_gaps": summary.idle_gaps}
+                            "idle_gaps": summary.idle_gaps[:layers.TOP]}
     out["checks"] = checks
     return out
+
+
+def run(workload: str, seed: int, seconds: float, trace: bool, *,
+        t_process: float, root: Path = ROOT, require_tpu: bool = True,
+        fault=None, log=print) -> dict:
+    """Make one run and return the result line's object."""
+    m = measure(workload, seed, seconds, trace, t_process=t_process, root=root,
+                require_tpu=require_tpu, fault=fault, log=log)
+    return report(m, workload, seed, trace, log=log)
 
 
 def main(argv=None, *, t_process: float) -> int:
@@ -492,8 +542,12 @@ def main(argv=None, *, t_process: float) -> int:
     args = ap.parse_args(argv)
     log = lambda msg: print(msg, file=sys.stderr, flush=True)
     try:
-        out = run(args.workload, args.seed, args.seconds, bool(args.trace),
-                  t_process=t_process, log=log)
+        # measure() is called from here, not through run(): with that one
+        # Python frame fewer above the warm-up, lowering its programs took
+        # about 6 s less on a TPU v5e, a tenth of set-up
+        m = measure(args.workload, args.seed, args.seconds, bool(args.trace),
+                    t_process=t_process, log=log)
+        out = report(m, args.workload, args.seed, bool(args.trace), log=log)
     except Refused as e:
         log(f"bench: refused: {e}")
         return 2

@@ -321,18 +321,18 @@ def decode_attn_pct(s: Summary) -> Optional[float]:
 
 def prefill_ms_per_ktok(s: Summary, prefill_tokens: int) -> Optional[float]:
     """Device time of the admission entry points per thousand real prompt
-    tokens admitted (``prefill_tokens``: the counter's change over the
-    window), in ms."""
+    tokens admitted (``prefill_tokens``: the scheduler counter's change
+    over the window), in ms."""
     if prefill_tokens <= 0:
         return None
     return sum(s.entry_s.get(e, 0.0) for e in PREFILL_ENTRIES) / (prefill_tokens / 1000) * 1e3
 
 
-def admit_pad_pct(stats0: dict, stats1: dict) -> Optional[float]:
+def admit_pad_pct(counters: dict) -> Optional[float]:
     """Share of the prefilled rows x bucketed lengths that were padding,
-    from the scheduler's counters at the window's start and end, in %."""
-    padded = stats1["prefill_padded_tokens"] - stats0["prefill_padded_tokens"]
-    real = stats1["prefill_tokens"] - stats0["prefill_tokens"]
+    from the change of the scheduler's counters over the window, in %."""
+    padded = counters.get("prefill_padded_tokens", 0)
+    real = counters.get("prefill_tokens", 0)
     return 100.0 * (padded - real) / padded if padded > 0 else None
 
 

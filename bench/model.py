@@ -1,43 +1,69 @@
-"""A configuration file as the program runs it, and its weights.
+"""A configuration file as the program runs it, its sizes and its weights.
 
 A configuration is a JSON file under ``bench/configs`` that holds the
 published ``config.json`` keys as they are run (``reduced`` names each key
-changed from the source, with its published value). This module turns it
-into the program's ``ModelConfig`` for a cell's FedAttn participants N and
-sync interval H, and draws the weights from the run's seed.
+changed from the source, with its published value). Its ``model_type``
+names the architecture module ``bench/arch/<model_type>.py``, which turns
+it into the sizes (a :class:`Dims`, or a subclass with fields of its own),
+the program's ``ModelConfig`` for a cell's FedAttn participants N and sync
+interval H, the weights drawn from the run's seed, the tree the program
+consumes, the float32 reference layer and the FLOPs of a layer.
+
+An architecture module provides::
+
+    dims(cfg, participants, sync_interval) -> Dims   # with model_type set
+    program_config(cfg, dm) -> repro.types.ModelConfig
+    draw(key, dm) -> weights in the benchmark layout (below)
+    program_params(w, dm) -> the same arrays in the program's tree
+    layer(x, lw, pos, seg, *, dm, index, quant) -> x   # bench/reference.py
+    weight_flops(dm, index) -> int   # a layer's weight FLOPs per token
+    pair_flops(dm, index) -> int     # its attention FLOPs per visible pair
+
+and, where its attention mask is not the plain FedAttn one, its own
+``prefill_flops(dm, L)`` and ``decode_flops(dm, L, m_lo, m_hi)``
+(``bench/flops.py``).
 
 The weights are the benchmark's own: one jitted call on the device draws
-them in the served dtype, in the layout below, and :func:`program_params`
-hands the same arrays to the program in the tree its scan-form entry points
-consume. The reference (``bench/reference.py``) reads the benchmark layout
-and nothing the program made.
+them in the served dtype, and :func:`program_params` hands the same arrays
+to the program in the tree its scan-form entry points consume. The
+reference (``bench/reference.py``) reads the benchmark layout and nothing
+the program made.
 
 Benchmark layout (P = n_layers / H sync periods, one dict per layer slot of
 a period, each leaf with a leading (P,) axis; layer ``p*H + i`` is slot
-``i`` of period ``p``)::
+``i`` of period ``p``; the keys of a slot are the module's)::
 
     {"embed": (Vp, d), "final_ln": (d,), "head": (d, Vp) or absent,
-     "layers": [{"ln1", "wq", "bq", "wk", "bk", "wv", "bv", "wo",
-                 "ln2", "wg", "wu", "wd"}] * H}
+     "layers": [{...}] * H}
 """
 from __future__ import annotations
 
+import importlib
 import json
 from dataclasses import dataclass
 from pathlib import Path
 
 import numpy as np
 
+
+class Refused(Exception):
+    """The run cannot be made here (no chip, too few chips, bad cell)."""
+
+
 @dataclass(frozen=True)
 class Dims:
-    """The sizes the harness, the FLOP count and the reference need."""
+    """The sizes the harness, the FLOP count and the reference need.
 
+    Frozen and hashable: the reference's jitted layers take it as a static
+    argument. An architecture module may subclass it with fields of its
+    own, frozen likewise."""
+
+    model_type: str
     n_layers: int
     d: int
     n_q: int
     n_kv: int
     d_head: int
-    d_ff: int
     vocab: int
     vocab_rows: int  # embedding rows, padded as the program pads them
     tied: bool
@@ -62,43 +88,35 @@ def load_json(path: Path) -> dict:
         return json.load(f)
 
 
+def arch(model_type: str):
+    """The architecture module ``bench/arch/<model_type>.py``."""
+    name = f"bench.arch.{model_type}"
+    if not model_type.isidentifier():
+        raise Refused(f"model_type {model_type!r} names no module")
+    try:
+        return importlib.import_module(name)
+    except ModuleNotFoundError as e:
+        if e.name != name:
+            raise
+        raise Refused(f"no architecture module bench/arch/{model_type}.py "
+                      f"for model_type {model_type!r}") from None
+
+
+def vocab_rows(vocab: int) -> int:
+    """Embedding rows as the program pads them: up to a multiple of 512."""
+    return vocab if vocab % 512 == 0 or vocab < 512 else vocab + (-vocab) % 512
+
+
 def dims(cfg: dict, participants: int, sync_interval: int) -> Dims:
-    if cfg.get("hidden_act") != "silu" or cfg.get("model_type") != "qwen2":
-        raise ValueError(
-            f"{cfg.get('name')}: only qwen2-family configurations (SwiGLU, "
-            "RMSNorm, GQA with q/k/v bias) are drawn by this module"
-        )
-    n_layers = int(cfg["num_hidden_layers"])
-    if n_layers % sync_interval:
-        raise ValueError(f"{n_layers} layers are not whole periods of H={sync_interval}")
-    d, n_q = int(cfg["hidden_size"]), int(cfg["num_attention_heads"])
-    vocab = int(cfg["vocab_size"])
-    rows = vocab if vocab % 512 == 0 or vocab < 512 else vocab + (-vocab) % 512
-    return Dims(
-        n_layers=n_layers, d=d, n_q=n_q,
-        n_kv=int(cfg["num_key_value_heads"]), d_head=d // n_q,
-        d_ff=int(cfg["intermediate_size"]), vocab=vocab, vocab_rows=rows,
-        tied=bool(cfg["tie_word_embeddings"]), eps=float(cfg["rms_norm_eps"]),
-        theta=float(cfg["rope_theta"]), dtype=str(cfg["torch_dtype"]),
-        sync_interval=sync_interval, participants=participants,
-    )
+    dm = arch(str(cfg.get("model_type"))).dims(cfg, participants, sync_interval)
+    if dm.n_layers % sync_interval:
+        raise ValueError(f"{dm.n_layers} layers are not whole periods of H={sync_interval}")
+    return dm
 
 
 def program_config(cfg: dict, dm: Dims):
     """The program's ``ModelConfig`` for this configuration and cell."""
-    from repro.types import FedAttnConfig, LayerSpec, ModelConfig
-
-    H = dm.sync_interval
-    return ModelConfig(
-        name=cfg["name"], arch_type="dense", n_layers=dm.n_layers,
-        d_model=dm.d, n_heads=dm.n_q, n_kv_heads=dm.n_kv, d_head=dm.d_head,
-        d_ff=dm.d_ff, vocab_size=dm.vocab, qkv_bias=True,
-        rope_theta=dm.theta, norm_eps=dm.eps, tie_embeddings=dm.tied,
-        dtype=dm.dtype,
-        pattern=tuple(LayerSpec(kind="attn", sync=(i == H - 1)) for i in range(H)),
-        fedattn=FedAttnConfig(n_participants=dm.participants, sync_interval=H),
-        source=cfg["source"],
-    )
+    return arch(dm.model_type).program_config(cfg, dm)
 
 
 def seeds(seed: int) -> tuple[int, np.random.Generator]:
@@ -112,68 +130,17 @@ def seeds(seed: int) -> tuple[int, np.random.Generator]:
     return w_key, np.random.default_rng(ss.spawn(1)[0])
 
 
-def _draw(key, dm: Dims):
-    import jax
-    import jax.numpy as jnp
-
-    dt = jnp.dtype(dm.dtype)
-    P, H = dm.n_periods, dm.sync_interval
-    d, f, dh = dm.d, dm.d_ff, dm.d_head
-    q, kv = dm.n_q * dh, dm.n_kv * dh
-    keys = iter(jax.random.split(key, 4 + 12 * H))
-
-    def normal(shape, std):
-        return (jax.random.normal(next(keys), shape, jnp.float32) * std).astype(dt)
-
-    def scale(shape):
-        return (1.0 + 0.1 * jax.random.normal(next(keys), shape, jnp.float32)).astype(dt)
-
-    layers = []
-    for _ in range(H):
-        layers.append({
-            "ln1": scale((P, d)),
-            "wq": normal((P, d, q), d ** -0.5), "bq": normal((P, q), 0.1),
-            "wk": normal((P, d, kv), d ** -0.5), "bk": normal((P, kv), 0.1),
-            "wv": normal((P, d, kv), d ** -0.5), "bv": normal((P, kv), 0.1),
-            "wo": normal((P, q, d), q ** -0.5),
-            "ln2": scale((P, d)),
-            "wg": normal((P, d, f), d ** -0.5), "wu": normal((P, d, f), d ** -0.5),
-            "wd": normal((P, f, d), f ** -0.5),
-        })
-    w = {
-        "embed": normal((dm.vocab_rows, d), d ** -0.5),
-        "final_ln": scale((d,)),
-        "layers": layers,
-    }
-    if not dm.tied:
-        w["head"] = normal((d, dm.vocab_rows), d ** -0.5)
-    return w
-
-
 def make_weights(dm: Dims, w_key: int):
     """Every weight drawn on the device by one jitted call, in the served
-    dtype (bfloat16 for both configurations)."""
+    dtype."""
     import functools
 
     import jax
 
-    return jax.jit(functools.partial(_draw, dm=dm))(jax.random.key(w_key))
+    draw = arch(dm.model_type).draw
+    return jax.jit(functools.partial(draw, dm=dm))(jax.random.key(w_key))
 
 
-def program_params(w: dict) -> dict:
+def program_params(w: dict, dm: Dims) -> dict:
     """The same arrays in the program's scan-form tree (no copy)."""
-    return {
-        "embed": {"tok": w["embed"]},
-        "stacked": [
-            {
-                "norm1": {"scale": s["ln1"]},
-                "attn": {k: s[k] for k in ("wq", "wk", "wv", "wo", "bq", "bk", "bv")},
-                "norm2": {"scale": s["ln2"]},
-                "ffn": {"w_gate": s["wg"], "w_up": s["wu"], "w_down": s["wd"]},
-            }
-            for s in w["layers"]
-        ],
-        "remainder": [],
-        "final_norm": {"scale": w["final_ln"]},
-        "head": {"w": w["head"]} if "head" in w else {},
-    }
+    return arch(dm.model_type).program_params(w, dm)

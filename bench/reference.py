@@ -1,13 +1,20 @@
-"""The plain reference: a float32 FedAttn forward of a qwen2-family model.
+"""The plain reference: a float32 FedAttn forward of the configuration's
+architecture.
 
-Written from the published architecture (Qwen2: RMSNorm, RoPE, GQA with
-q/k/v bias, SwiGLU, tied or untied head) and the FedAttn visibility rule
-(``bench/flops.py`` states it), in plain ``jax.numpy`` with every matrix
-product at ``precision=HIGHEST``. It imports nothing of the program and
-reads only the benchmark's own weights (``bench/model.py`` layout).
+What every architecture shares is here: positions and participant
+segments, the FedAttn visibility rule (``bench/flops.py`` states it;
+:func:`visible`), attention over it in blocks of query rows
+(:func:`attend`), padding, the embedding and the head, matrix products at
+``precision=HIGHEST`` (:func:`mm`), RMSNorm (:func:`rms`) and the fp8
+control's rounding. Each layer's forward is the architecture module's
+(``bench/arch/<model_type>.py``: ``layer``), written from the published
+architecture in plain ``jax.numpy`` on these helpers. Nothing here imports
+the program; it reads only the benchmark's own weights (``bench/model.py``
+layout).
 
-One departure from Qwen2 follows the program: embedded tokens are scaled
-by sqrt(hidden_size) (see the configuration files' ``assumed``).
+One departure from the published models follows the program: embedded
+tokens are scaled by sqrt(hidden_size) (see the configuration files'
+``assumed``).
 
 It runs layer by layer, one jitted layer at a time with the layer's
 weights cast to float32 inside, and attends in blocks of query rows, so
@@ -25,8 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import model
 from bench.flops import segment_sizes
-from bench.model import Dims
 
 HI = jax.lax.Precision.HIGHEST
 Q_BLOCK = 512  # query rows per attention block
@@ -42,7 +49,7 @@ def _fake_fp8(x, axis):
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _mm(x, w, quant):
+def mm(x, w, quant):
     """x (..., k) @ w (k, n) in float32."""
     w = w.astype(jnp.float32)
     if quant == "fp8":
@@ -50,59 +57,51 @@ def _mm(x, w, quant):
     return jnp.matmul(x, w, precision=HI)
 
 
-def _rms(x, scale, eps):
+def rms(x, scale, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale.astype(jnp.float32)
 
 
-def _rope(x, pos, theta):
-    """Rotate-half RoPE. x (T, heads, dh), pos (T,)."""
-    dh = x.shape[-1]
-    inv = 1.0 / theta ** (jnp.arange(0, dh, 2, dtype=jnp.float32) / dh)
-    ang = pos.astype(jnp.float32)[:, None] * inv  # (T, dh/2)
-    cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
-    x1, x2 = x[..., : dh // 2], x[..., dh // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+def visible(pos_q, seg_q, pos_k, seg_k, sync: bool):
+    """The FedAttn mask of queries at (pos_q, seg_q) over keys at (pos_k,
+    seg_k): causal; keys of segment -1 (padding) are no one's; a local
+    layer's query sees its own participant's keys only, a sync layer's
+    every earlier key."""
+    vis = pos_q[:, None] >= pos_k[None, :]
+    vis &= seg_k[None, :] >= 0
+    if not sync:
+        vis &= seg_q[:, None] == seg_k[None, :]
+    return vis
 
 
-@functools.partial(jax.jit, static_argnames=("dm", "sync", "quant"))
-def _layer(x, lw, pos, seg, *, dm: Dims, sync: bool, quant):
-    T = x.shape[0]
-    dh, g = dm.d_head, dm.n_q // dm.n_kv
-    h = _rms(x, lw["ln1"], dm.eps)
-    q = (_mm(h, lw["wq"], quant) + lw["bq"].astype(jnp.float32)).reshape(T, dm.n_q, dh)
-    k = (_mm(h, lw["wk"], quant) + lw["bk"].astype(jnp.float32)).reshape(T, dm.n_kv, dh)
-    v = (_mm(h, lw["wv"], quant) + lw["bv"].astype(jnp.float32)).reshape(T, dm.n_kv, dh)
-    q, k = _rope(q, pos, dm.theta), _rope(k, pos, dm.theta)
-    q = q.reshape(T, dm.n_kv, g, dh)  # query head j reads kv head j // g
+def attend(q, k, v, pos, seg, *, sync: bool):
+    """Softmax attention under :func:`visible`, in blocks of query rows.
+    q (T, n_q, dh), k and v (T, n_kv, dh): query head j reads kv head
+    j // (n_q // n_kv). Returns (T, n_q * dh)."""
+    T, n_q, dh = q.shape
+    n_kv = k.shape[1]
+    q = q.reshape(T, n_kv, n_q // n_kv, dh)
     outs = []
     for lo in range(0, T, Q_BLOCK):
         qb = q[lo: lo + Q_BLOCK]
         s = jnp.einsum("qkgd,tkd->kgqt", qb, k, precision=HI) * dh ** -0.5
-        vis = pos[lo: lo + Q_BLOCK, None] >= pos[None, :]
-        vis &= seg[None, :] >= 0
-        if not sync:
-            vis &= seg[lo: lo + Q_BLOCK, None] == seg[None, :]
+        vis = visible(pos[lo: lo + Q_BLOCK], seg[lo: lo + Q_BLOCK], pos, seg, sync)
         s = jnp.where(vis, s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1)
         p = jnp.where(vis, p, 0.0)  # rows with nothing visible (padding)
         outs.append(jnp.einsum("kgqt,tkd->qkgd", p, v, precision=HI))
-    o = jnp.concatenate(outs, 0).reshape(T, dm.n_q * dh)
-    x = x + _mm(o, lw["wo"], quant)
-    h = _rms(x, lw["ln2"], dm.eps)
-    f = jax.nn.silu(_mm(h, lw["wg"], quant)) * _mm(h, lw["wu"], quant)
-    return x + _mm(f, lw["wd"], quant)
+    return jnp.concatenate(outs, 0).reshape(T, n_q * dh)
 
 
 @functools.partial(jax.jit, static_argnames=("dm",))
-def _embed(table, tokens, *, dm: Dims):
+def _embed(table, tokens, *, dm: model.Dims):
     return jnp.take(table, tokens, axis=0).astype(jnp.float32) * np.float32(dm.d ** 0.5)
 
 
 @functools.partial(jax.jit, static_argnames=("dm", "quant"))
-def _head(x, final_ln, table, *, dm: Dims, quant):
+def _head(x, final_ln, table, *, dm: model.Dims, quant):
     """``table`` is the (d, Vp) head, or the (Vp, d) embedding when tied."""
-    h = _rms(x, final_ln, dm.eps)
-    return _mm(h, table.T if dm.tied else table, quant)[:, : dm.vocab]
+    h = rms(x, final_ln, dm.eps)
+    return mm(h, table.T if dm.tied else table, quant)[:, : dm.vocab]
 
 
 def positions_and_segments(T: int, L: int, n: int) -> tuple[np.ndarray, np.ndarray]:
@@ -115,11 +114,12 @@ def positions_and_segments(T: int, L: int, n: int) -> tuple[np.ndarray, np.ndarr
     return np.arange(T, dtype=np.int32), seg
 
 
-def logits(w: dict, dm: Dims, tokens: np.ndarray, L: int, first: int, *, quant=None):
+def logits(w: dict, dm: model.Dims, tokens: np.ndarray, L: int, first: int, *, quant=None):
     """Float32 logits at positions first..T-1 of the sequence ``tokens``
     (T,), whose first L tokens are the prompt: a (rows, vocab) array whose
     first T - first rows are those positions (rows is that count rounded up
     to a multiple of ``PAD_OUT``; the rest are padding)."""
+    layer = model.arch(dm.model_type).layer
     T = int(tokens.shape[0])
     Tp = -(-T // PAD_T) * PAD_T
     pos, seg = positions_and_segments(T, L, dm.participants)
@@ -128,10 +128,10 @@ def logits(w: dict, dm: Dims, tokens: np.ndarray, L: int, first: int, *, quant=N
     toks = np.concatenate([tokens.astype(np.int32), np.zeros(Tp - T, np.int32)])
     pos, seg = jnp.asarray(pos), jnp.asarray(seg)
     x = _embed(w["embed"], jnp.asarray(toks), dm=dm)
-    for layer in range(dm.n_layers):
-        p, i = divmod(layer, dm.sync_interval)
-        lw = {k: a[p] for k, a in w["layers"][i].items()}
-        x = _layer(x, lw, pos, seg, dm=dm, sync=dm.is_sync(layer), quant=quant)
+    for i in range(dm.n_layers):
+        p, slot = divmod(i, dm.sync_interval)
+        lw = {k: a[p] for k, a in w["layers"][slot].items()}
+        x = layer(x, lw, pos, seg, dm=dm, index=i, quant=quant)
     n_out = T - first
     rows = -(-n_out // PAD_OUT) * PAD_OUT
     xs = jax.lax.dynamic_slice_in_dim(

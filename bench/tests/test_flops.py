@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 from bench import flops
-from bench.model import Dims
+from bench.arch import qwen2
 from bench.reference import positions_and_segments
 
 
@@ -34,9 +34,10 @@ def test_decode_pairs_are_the_visible_ones(L, n, lo, hi):
 
 
 def test_flops_split_into_weights_attention_and_head():
-    dm = Dims(n_layers=4, d=8, n_q=2, n_kv=1, d_head=4, d_ff=16, vocab=10,
-              vocab_rows=10, tied=False, eps=1e-6, theta=1e4, dtype="float32",
-              sync_interval=2, participants=2)
+    """Qwen2's count, taken layer by layer from its architecture module."""
+    dm = qwen2.Dims(model_type="qwen2", n_layers=4, d=8, n_q=2, n_kv=1, d_head=4, d_ff=16,
+                    vocab=10, vocab_rows=10, tied=False, eps=1e-6, theta=1e4,
+                    dtype="float32", sync_interval=2, participants=2)
     L = 6
     per_token = 2 * (8 * 8 + 2 * 8 * 4 + 8 * 8 + 3 * 8 * 16)
     local, sync = flops.prefill_pairs(L, 2)

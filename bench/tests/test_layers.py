@@ -155,9 +155,9 @@ def test_numbers_without_their_data_are_none():
     assert layers.decode_attn_pct(s) is None
     assert layers.prefill_ms_per_ktok(s, 0) is None
     assert layers.step_host_ms(s) is None
-    zero = {"prefill_tokens": 0, "prefill_padded_tokens": 0}
-    assert layers.admit_pad_pct(zero, zero) is None
-    assert layers.admit_pad_pct(zero, {"prefill_tokens": 35, "prefill_padded_tokens": 64}) \
+    assert layers.admit_pad_pct({"prefill_tokens": 0, "prefill_padded_tokens": 0}) is None
+    assert layers.admit_pad_pct({}) is None
+    assert layers.admit_pad_pct({"prefill_tokens": 35, "prefill_padded_tokens": 64}) \
         == pytest.approx(100 * 29 / 64)
     assert layers.summarize(layers.Events(base=trace.Events())) is None
 

@@ -1,14 +1,19 @@
-"""The metric readers' arithmetic on a hand-made record."""
+"""The metric readers' arithmetic on a hand-made record, and the readers
+of the traced window and the scheduler's counters against the numbers of
+bench/layers.py."""
+from pathlib import Path
+
 import numpy as np
 import pytest
 
-from bench import flops, harness
-from bench.model import Dims
+from bench import flops, harness, layers
+from bench.arch import qwen2
+from bench.tests.test_layers import nested
 from bench.workload import Req
 
-DM = Dims(n_layers=2, d=4, n_q=2, n_kv=1, d_head=2, d_ff=8, vocab=16, vocab_rows=16,
-          tied=False, eps=1e-6, theta=1e4, dtype="float32", sync_interval=2,
-          participants=2)
+DM = qwen2.Dims(model_type="qwen2", n_layers=2, d=4, n_q=2, n_kv=1, d_head=2, d_ff=8,
+                vocab=16, vocab_rows=16, tied=False, eps=1e-6, theta=1e4, dtype="float32",
+                sync_interval=2, participants=2)
 
 
 def tick(t0, t1, admitted=0, resident=4, tokens=8, prefill=0, work=()):
@@ -64,3 +69,39 @@ def test_step_arithmetic():
     assert read("mfu", rec) == pytest.approx(100 * work / (0.8 * 1000.0))
     assert read("setup_s", rec) == 12.5
     assert read("idle_pct", rec) is None  # no trace in this record
+
+
+SCOPED = Path(__file__).parent / "data" / "scoped.xplane.pb"
+AT_START = {"prefill_tokens": 1200, "prefill_padded_tokens": 2048, "full_prefills": 5}
+AT_END = {"prefill_tokens": 1735, "prefill_padded_tokens": 2880, "full_prefills": 9}
+
+
+def traced(summary):
+    rec = record()
+    rec.counters = {k: AT_END[k] - AT_START[k] for k in AT_END}
+    rec.trace = summary
+    return rec
+
+
+@pytest.mark.parametrize("events", [lambda: layers.read(str(SCOPED)), nested],
+                         ids=["scoped.xplane.pb", "nested"])
+def test_trace_and_counter_readers_are_bench_layers_numbers(events):
+    s = layers.summarize(events())
+    rec = traced(s)
+    want = {
+        "decode_device_ms": layers.decode_device_ms(s, rec.steps_per_admit),
+        "decode_attn_pct": layers.decode_attn_pct(s),
+        "prefill_ms_per_ktok": layers.prefill_ms_per_ktok(s, 535),
+        "admit_pad_pct": 100 * (832 - 535) / 832,
+        "step_host_ms": layers.step_host_ms(s),
+        "idle_pct": s.base.idle_pct,
+    }
+    assert {name: read(name, rec) for name in want} == want
+    assert want["step_host_ms"] is not None and want["prefill_ms_per_ktok"] is not None
+
+
+def test_trace_readers_without_a_trace_read_nothing():
+    rec = record()
+    for name in ("decode_device_ms", "decode_attn_pct", "prefill_ms_per_ktok",
+                 "step_host_ms", "admit_pad_pct"):
+        assert read(name, rec) is None
