@@ -58,6 +58,13 @@ Checks per entry point:
                scalar-prefetched page table; a pool-shaped gather means
                the step silently fell back to the densify-then-attend
                read path.
+``pool_scan``  (:func:`pool_carry_issues`, paged pools in scan form on one
+               device) no ``scan`` equation of the decode/verify step takes
+               a stacked pool leaf (``pk``/``pv``/``sk``/``sv``) as a
+               scanned input or emits one as a stacked output — the pool
+               rides the layer scan's carry and is written in place
+               (models/transformer.apply_layers_decode_scan); a scanned
+               pool slices out and restacks every period's pages each step.
 
 Usage: ``python -m repro.analysis --jaxpr`` or the parametrized
 tier-1 test (tests/test_analysis.py) which sweeps every config in
@@ -87,7 +94,7 @@ class AuditIssue:
     """One contract violation found in a traced entry point."""
 
     entry: str
-    check: str  # f64 | callback | donation | consts | scaling
+    check: str  # f64 | callback | donation | consts | scaling | pool_*
     detail: str
 
     def __str__(self) -> str:
@@ -419,6 +426,7 @@ def audit_quant_pool(
     issues = audit_entries(
         entries, backend=backend, max_const_bytes=max_const_bytes
     )
+    issues.extend(pool_carry_issues(sched, entries))
 
     pool_rank = 4 if sched._plan is None else 5
     for e in entries:
@@ -485,6 +493,11 @@ def pool_gather_issues(
         for v in jaxpr.invars
         if getattr(v.aval, "ndim", 0) >= min_pool_rank
     }
+    # a scan-form pool is read through its flat view: the stacked leaf
+    # (n_periods, N, ...) with its first two dims merged
+    pool_shapes |= {
+        (s[0] * s[1],) + s[2:] for s in pool_shapes if len(s) >= 5
+    }
     issues: list[AuditIssue] = []
     for eqn in iter_eqns(jaxpr):
         if eqn.primitive.name != "gather":
@@ -498,6 +511,58 @@ def pool_gather_issues(
                 "step must read pages in-kernel via the prefetched page "
                 "table, not densify the pool (kernels/flash_decode)",
             ))
+    return issues
+
+
+def pool_leaf_shapes(cache) -> set:
+    """Shapes of the paged pool leaves (``pk``/``pv``/``sk``/``sv``) in a
+    cache pytree."""
+    from repro.models.transformer import POOL_KEYS
+
+    return {
+        tuple(leaf.shape)
+        for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]
+        if getattr(path[-1], "key", None) in POOL_KEYS
+    }
+
+
+def pool_scan_issues(name: str, traced, pool_shapes) -> list[AuditIssue]:
+    """Ban stacked pool leaves from the scanned inputs and stacked outputs
+    of every ``scan`` equation (recursing into sub-jaxprs). Carried
+    operands are allowed — that is where the pool belongs."""
+    issues: list[AuditIssue] = []
+    for eqn in iter_eqns(traced.jaxpr.jaxpr):
+        if eqn.primitive.name != "scan":
+            continue
+        n_carry = eqn.params["num_carry"]
+        xs = eqn.invars[eqn.params["num_consts"] + n_carry:]
+        ys = eqn.outvars[n_carry:]
+        for role, vs in (("scanned input", xs), ("stacked output", ys)):
+            for v in vs:
+                shape = tuple(getattr(getattr(v, "aval", None), "shape", ()))
+                if shape in pool_shapes:
+                    issues.append(AuditIssue(
+                        name, "pool_scan",
+                        f"pool-shaped {role} {shape} of a scan — the pool "
+                        "must ride the layer scan's carry and be written "
+                        "in place (models/transformer."
+                        "apply_layers_decode_scan)",
+                    ))
+    return issues
+
+
+def pool_carry_issues(scheduler, entries) -> list[AuditIssue]:
+    """:func:`pool_scan_issues` over a scheduler's decode and verify
+    entries. Applies to a paged pool in scan form on one device; a serving
+    mesh keeps its page-sharded pool as a scanned input."""
+    sched = scheduler
+    if not sched._paged or sched._plan is None or sched._spmd is not None:
+        return []
+    shapes = pool_leaf_shapes(sched.cache["stacked"])
+    issues: list[AuditIssue] = []
+    for e in entries:
+        if e.name in ("scheduler.decode_step", "scheduler.verify_step"):
+            issues.extend(pool_scan_issues(e.name, e.traced, shapes))
     return issues
 
 
@@ -537,7 +602,7 @@ def audit_fused_decode(
             issues.extend(pool_gather_issues(
                 e.name, e.traced, min_pool_rank=pool_rank
             ))
-    return issues
+    return issues + pool_carry_issues(sched, entries)
 
 
 def audit_engine(
@@ -548,6 +613,7 @@ def audit_engine(
     """Trace + audit every serving entry point of an engine (and, with
     ``with_pool``, of a small scheduler pool over it)."""
     entries = trace_engine_entries(engine, B=B, L=L, n_new=n_new)
+    issues: list[AuditIssue] = []
     if with_pool:
         from repro.serving.scheduler import ContinuousBatchingScheduler
 
@@ -563,8 +629,10 @@ def audit_engine(
             engine, max_slots=max_slots, capacity=cap,
             spec_k=2 if attn_only else 0,
         )
-        entries.extend(trace_scheduler_entries(sched))
-    return audit_entries(
+        pool_entries = trace_scheduler_entries(sched)
+        entries.extend(pool_entries)
+        issues = pool_carry_issues(sched, pool_entries)
+    return issues + audit_entries(
         entries, backend=backend, max_const_bytes=max_const_bytes
     )
 

@@ -191,7 +191,13 @@ def init_paged_cache(
 ):
     """Block-paged decode caches, loop or scan form (mirrors init_cache /
     init_cache_scan; scan form stacks pool leaves to (n_periods, num_pages,
-    page_size, nkv, dh)). ``kv_quant`` selects a quantized pool codec
+    page_size, nkv, dh)). The stacked layout is what the scheduler, the
+    slot writes and the prefix cache see; inside the decode step's layer
+    scan the pool rides the carry as one flat pool of ``n_periods *
+    num_pages`` pages, period ``i`` addressing its own pages at offset
+    ``i * num_pages`` (apply_layers_decode_scan — except under a serving
+    mesh, whose page-sharded pool stays a scanned input). ``kv_quant``
+    selects a quantized pool codec
     (attention-only stacks; see init_paged_layer_cache); ``mass_width``
     adds the per-slot attention-mass accumulator leaf (the 'attnmass'
     decode-stats feed, ibid.)."""
@@ -573,6 +579,11 @@ def cache_pspecs(cache, cache_axes):
     return [layer(c) for c in cache]
 
 
+#: paged-pool leaves of an attention layer's cache (codes, then the
+#: quantized pool's per-page scales) — the leaves the decode scan carries
+POOL_KEYS = ("pk", "pv", "sk", "sv")
+
+
 def apply_layers_decode_scan(
     params: Params,
     cache: Params,
@@ -589,16 +600,33 @@ def apply_layers_decode_scan(
 ):
     """All decoder layers as one ``lax.scan`` over the plan's scan units.
 
-    The hidden state is the scan carry; the per-period (params, cache
-    [, contributed-rows]) stacks are the scanned inputs and the updated
-    caches come back as the stacked outputs — so the trace contains each
-    unit's layers exactly once. Per-round sparse-exchange rows are sliced
-    per scan step ((n_periods, syncs_per_period, ...) reshape), keeping
-    round ordering identical to the python-loop path. ``contributed`` is
-    rounds-first: ``(rounds, capacity)`` shared rows or ``(rounds, B,
-    capacity)`` per-row rows (coalesced multi-request admission — each
-    batch row carries its own request's exchange mask).
+    The hidden state is the scan carry; the per-period params [and
+    contributed rows] are the scanned inputs, and so are the per-slot
+    cache leaves (dense ``k``/``v``, recurrent state, the ``am`` mass
+    accumulator), whose updates come back as the stacked outputs — so
+    the trace contains each unit's layers exactly once. Per-round
+    sparse-exchange rows are sliced per scan step ((n_periods,
+    syncs_per_period, ...) reshape), keeping round ordering identical to
+    the python-loop path. ``contributed`` is rounds-first: ``(rounds,
+    capacity)`` shared rows or ``(rounds, B, capacity)`` per-row rows
+    (coalesced multi-request admission — each batch row carries its own
+    request's exchange mask).
+
+    A paged pool (``pages`` given) rides the CARRY instead: each stacked
+    pool leaf ``(n_periods, N, ...)`` (``pk``/``pv``, and the ``sk``/``sv``
+    scales of a quantized pool) is viewed as one flat pool of
+    ``n_periods * N`` pages, and period ``i`` addresses its own pages
+    through tables offset by ``i * N`` (serving/paging.stacked_pages;
+    holes stay holes for the whole flat pool). Each layer writes its new
+    rows into the carried buffer in place and reads the pages it needs
+    from it, so no period's page array is sliced out and no stacked pool
+    output is built. A serving mesh keeps the pool as a scanned input:
+    the pool is sharded on its page axis, and merging the period axis
+    into it would break the shard-local page ranges.
     Returns (x, new_cache) with the cache still in scan form."""
+    from repro.distributed import runtime
+    from repro.serving import paging
+
     spp = plan.syncs_per_period
     contrib_body = None
     if contributed is not None and spp > 0:
@@ -606,8 +634,28 @@ def apply_layers_decode_scan(
             (plan.n_periods, spp) + contributed.shape[1:]
         )
 
-    def unit(h, per_params, per_cache, contrib_rows):
-        new_c = []
+    n_per = plan.n_periods
+    stacked = cache["stacked"]
+    n_pages = next((c["pk"].shape[1] for c in stacked if "pk" in c), None)
+    carry_pool = (pages is not None and n_pages is not None
+                  and not runtime.active())
+    carried = POOL_KEYS if carry_pool else ()
+
+    def split(c):  # (carried pool leaves, per-slot leaves) of a layer cache
+        return ({k: v for k, v in c.items() if k in carried},
+                {k: v for k, v in c.items() if k not in carried})
+
+    pools = [split(c)[0] for c in stacked]
+    rest = [split(c)[1] for c in stacked]
+    pools = jax.tree.map(lambda v: v.reshape((-1,) + v.shape[2:]), pools)
+
+    def body(carry, xs):
+        h, pools = carry
+        period, per_params, per_rest, contrib_rows = xs
+        pg = pages
+        if carry_pool:
+            pg = paging.stacked_pages(pages, period, n_pages, n_per)
+        new_pools, new_rest = [], []
         ci = 0
         for i, spec in enumerate(plan.specs):
             row = None
@@ -615,21 +663,21 @@ def apply_layers_decode_scan(
                 row = contrib_rows[ci]
                 ci += 1
             h, c = apply_layer_decode(
-                per_params[i], per_cache[i], h, cache_len, dctx, 0, spec,
-                config, sync=plan.sync[i], backend=backend, moe_impl=moe_impl,
-                contributed=row, pages=pages,
+                per_params[i], {**per_rest[i], **pools[i]}, h, cache_len,
+                dctx, 0, spec, config, sync=plan.sync[i], backend=backend,
+                moe_impl=moe_impl, contributed=row, pages=pg,
             )
-            new_c.append(c)
-        return h, new_c
+            p, r = split(c)
+            new_pools.append(p)
+            new_rest.append(r)
+        return (h, new_pools), new_rest
 
-    if contrib_body is None:
-        body = lambda h, xs: unit(h, xs[0], xs[1], None)
-        xs = (params["stacked"], cache["stacked"])
-    else:
-        body = lambda h, xs: unit(h, xs[0], xs[1], xs[2])
-        xs = (params["stacked"], cache["stacked"], contrib_body)
+    xs = (jnp.arange(n_per, dtype=jnp.int32), params["stacked"], rest,
+          contrib_body)
     with jax.named_scope(scopes.LAYER_SCAN):
-        x, new_stacked = jax.lax.scan(body, x, xs)
+        (x, pools), rest = jax.lax.scan(body, (x, pools), xs)
+    pools = jax.tree.map(lambda v: v.reshape((n_per, -1) + v.shape[1:]), pools)
+    new_stacked = [{**r, **p} for r, p in zip(rest, pools)]
 
     new_rem = []
     ri = plan.n_periods * spp

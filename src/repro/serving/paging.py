@@ -20,9 +20,10 @@ sentinel value is ``num_pages`` itself.
 Invariant analyzer: rule FED006 (repro.analysis.lint) rejects raw
 ``//``/``%`` arithmetic on page identifiers anywhere outside this module —
 every consumer composes :func:`page_split` / :func:`pages_for` /
-:func:`linear_pos` so the page-geometry convention has one point of
-change. The helpers are shape-polymorphic: they accept python ints, numpy
-arrays and traced jnp arrays alike (``//``/``%`` lower to lax ops).
+:func:`linear_pos` / :func:`stacked_pages` so the page-geometry
+convention has one point of change. The helpers are shape-polymorphic:
+they accept python ints, numpy arrays and traced jnp arrays alike
+(``//``/``%`` lower to lax ops).
 
 On top of the :class:`PageAllocator` (refcounted free list), the
 :class:`PrefixCache` keys page runs by the exact bytes of the request
@@ -36,6 +37,8 @@ from __future__ import annotations
 
 from collections import OrderedDict
 from typing import Callable, Optional
+
+import jax.numpy as jnp
 
 
 def pages_for(n, page_size: int):
@@ -52,6 +55,19 @@ def page_split(pos, page_size: int):
 def linear_pos(page_slot, offset, page_size: int):
     """Inverse of :func:`page_split`."""
     return page_slot * page_size + offset
+
+
+def stacked_pages(pages, period, num_pages: int, n_periods: int):
+    """Page tables addressing period ``period`` of ``n_periods`` stacked
+    pools viewed as ONE flat pool of ``n_periods * num_pages`` pages (the
+    scan-form decode carry, models/transformer.apply_layers_decode_scan):
+    page ``p`` of the period is flat page ``period * num_pages + p``.
+    Holes stay holes for the whole flat pool — every sentinel entry
+    (``>= num_pages``) maps to ``n_periods * num_pages``, never onto the
+    next period's page 0."""
+    return jnp.where(
+        pages < num_pages, pages + period * num_pages, n_periods * num_pages
+    )
 
 
 def padded_capacity(capacity: int, page_size: int) -> int:

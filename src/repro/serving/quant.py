@@ -126,20 +126,25 @@ def paged_write(pool, scales, new, page_idx, off):
     page has ``scales' == scales`` bit-exact and its re-encode ratio is
     EXACTLY 1.0 (the identity — resident codes never drift); a page whose
     amax grew rescales its resident codes once by old/new before the new
-    rows land encoded under the grown scale. Cost: one O(pool) rescale per
-    call — negligible beside the attention gather over the same pool."""
+    rows land encoded under the grown scale. Only the written pages are
+    re-encoded (gathered, rescaled, scattered back), so the cost is
+    O(rows written), not O(pool) — the pool may be the flat view of every
+    period of a scan-form stack (models/transformer)."""
     cmax = code_max(pool.dtype)
     x = new.astype(jnp.float32)
     row_scales = jnp.max(jnp.abs(x), axis=-1) / cmax  # (B, S, nkv)
     scales2 = scales.at[page_idx].max(row_scales, mode="drop")
-    ratio = jnp.where(
-        scales2 == scales, 1.0, scales / jnp.maximum(scales2, _TINY)
-    )
-    body = pool.astype(jnp.float32) * ratio[:, None, :, None]
+    N = pool.shape[0]
+    live = jnp.minimum(page_idx, N - 1)
+    old_s, new_s = jnp.take(scales, live, axis=0), jnp.take(scales2, live, axis=0)
+    ratio = jnp.where(new_s == old_s, 1.0, old_s / jnp.maximum(new_s, _TINY))
+    body = jnp.take(pool, live, axis=0).astype(jnp.float32)
+    body = body * ratio[..., None, :, None]  # (B, S, ps, nkv, dh)
     if jnp.dtype(pool.dtype) == jnp.dtype(jnp.int8):
         body = jnp.round(body)
     body = jnp.clip(body, -cmax, cmax).astype(pool.dtype)
-    N = pool.shape[0]
-    s_rows = jnp.take(scales2, jnp.minimum(page_idx, N - 1), axis=0)
-    codes = _encode(x, s_rows, pool.dtype)
-    return body.at[page_idx, off].set(codes, mode="drop"), scales2
+    # rows of one page carry identical re-encoded pages, so duplicate
+    # indices in this scatter agree; sentinel entries drop
+    pool = pool.at[page_idx].set(body, mode="drop")
+    codes = _encode(x, new_s, pool.dtype)
+    return pool.at[page_idx, off].set(codes, mode="drop"), scales2
