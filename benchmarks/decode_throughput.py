@@ -88,7 +88,7 @@ def main():
         records.append({
             "name": name,
             "n_new": args.n_new,
-            "layers_mode": engine.layers_mode,
+            "scan_plan": (engine._plan.period, engine._plan.n_periods),
             "tok_s_eager": tps_eager,
             "tok_s_jit": tps_jit,
             "speedup": speedup,

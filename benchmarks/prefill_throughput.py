@@ -93,7 +93,7 @@ def main():
         records.append({
             "name": name,
             "lengths": list(LENGTHS),
-            "layers_mode": eng.layers_mode,
+            "scan_plan": (eng._plan.period, eng._plan.n_periods),
             "prefill_ms_eager": dt_eager * 1e3,
             "prefill_ms_jit": dt_jit * 1e3,
             "speedup": speedup,

@@ -215,7 +215,7 @@ def main():
         "max_slots": args.max_slots,
         "steps_per_admit": args.steps_per_admit,
         "capacity": capacity,
-        "layers_mode": engine.layers_mode,
+        "scan_plan": (engine._plan.period, engine._plan.n_periods),
         "tok_s_sequential": tok_s_seq,
         "tok_s_stream": tok_s_stream,
         "speedup": speedup,

@@ -177,8 +177,7 @@ def prefill_logits(engine, req):
     L = toks.shape[1]
     Lp = engine.bucket_len(L)
     cap = Lp + engine._bucket_new(req.n_new)
-    plan = engine._plan if engine.layers_mode == "scan" else None
-    cache = engine.model.init_cache(1, cap, plan=plan)
+    cache = engine.model.init_cache(1, cap, plan=engine._plan)
     ctx = engine.build_context(L)
     last, _ = engine._prefill_compiled(toks, ctx, cache, None, L, Lp, cap)
     return last[0]
@@ -223,7 +222,8 @@ def one_chip(config, seed: int, *, expect_kernel: bool = True):
             params = engine.params
             print(f"init: {time.perf_counter() - t0:.2f}s, "
                   f"{'scan' if 'stacked' in params else 'loop'}-form weights, "
-                  f"layers lowered as {engine.layers_mode}")
+                  f"layers scanned as {engine._plan.n_periods} periods "
+                  f"of {engine._plan.period}")
         _, sched = serve(engine, reqs, label)
         audit_pool(sched, label, expect_kernel=expect_kernel and backend == "pallas")
         del sched

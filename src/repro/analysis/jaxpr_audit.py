@@ -251,7 +251,7 @@ def trace_engine_entries(
     Lp = engine.bucket_len(L)
     Nb = engine._bucket_new(n_new)
     capacity = Lp + Nb
-    plan = engine._plan if engine.layers_mode == "scan" else None
+    plan = engine._plan
     params = engine._run_params()
     ctx = engine.build_context(L)
     d0 = ctx.decode_template(capacity)
@@ -428,7 +428,7 @@ def audit_quant_pool(
     )
     issues.extend(pool_carry_issues(sched, entries))
 
-    pool_rank = 4 if sched._plan is None else 5
+    pool_rank = 5  # (n_periods, num_pages, page_size, nkv, dh)
     for e in entries:
         if e.name not in ("scheduler.decode_step", "scheduler.slot_write",
                           "scheduler.verify_step"):
@@ -553,10 +553,10 @@ def pool_scan_issues(name: str, traced, pool_shapes) -> list[AuditIssue]:
 
 def pool_carry_issues(scheduler, entries) -> list[AuditIssue]:
     """:func:`pool_scan_issues` over a scheduler's decode and verify
-    entries. Applies to a paged pool in scan form on one device; a serving
-    mesh keeps its page-sharded pool as a scanned input."""
+    entries. Applies to a paged pool on one device; a serving mesh keeps
+    its page-sharded pool as a scanned input."""
     sched = scheduler
-    if not sched._paged or sched._plan is None or sched._spmd is not None:
+    if not sched._paged or sched._spmd is not None:
         return []
     shapes = pool_leaf_shapes(sched.cache["stacked"])
     issues: list[AuditIssue] = []
@@ -596,12 +596,9 @@ def audit_fused_decode(
     issues = audit_entries(
         entries, backend=backend, max_const_bytes=max_const_bytes
     )
-    pool_rank = 4 if sched._plan is None else 5
     for e in entries:
         if e.name in ("scheduler.decode_step", "scheduler.verify_step"):
-            issues.extend(pool_gather_issues(
-                e.name, e.traced, min_pool_rank=pool_rank
-            ))
+            issues.extend(pool_gather_issues(e.name, e.traced, min_pool_rank=5))
     return issues + pool_carry_issues(sched, entries)
 
 
@@ -700,21 +697,15 @@ def audit_trace_scaling(
     make_engine: Callable[[int], object], *, depths: tuple[int, int] = (2, 4),
     tol: float = 1.6, B: int = 1, L: int = 8, n_new: int = 4,
 ) -> list[AuditIssue]:
-    """The O(period) contract, generalized from PR 2's decode-only pin:
-    for engines in scan mode, doubling the layer count must leave every
-    entry point's traced-jaxpr size within ``tol`` (the scan body is traced
-    once; only bookkeeping may grow).  ``make_engine(k)`` builds the engine
-    at ``n_layers = period * k``."""
+    """The O(period) contract of the decode-path entry points: doubling
+    the layer count must leave every entry point's traced-jaxpr size
+    within ``tol`` (the scan body is traced once; only bookkeeping may
+    grow). ``make_engine(k)`` builds the engine at ``n_layers = period *
+    k``. An engine whose plan is one period of the whole body traces every
+    layer, so its growth is reported here."""
     sizes: dict[int, dict[str, int]] = {}
     for k in depths:
         engine = make_engine(k)
-        if engine.layers_mode != "scan":
-            return [AuditIssue(
-                "trace_scaling", "scaling",
-                f"engine at depth multiplier {k} is not in scan mode — "
-                "O(period) tracing does not apply (loop mode is O(n_layers) "
-                "by construction)",
-            )]
         sizes[k] = {
             e.name: len(str(e.traced.jaxpr))
             for e in trace_engine_entries(engine, B=B, L=L, n_new=n_new)

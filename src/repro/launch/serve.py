@@ -229,13 +229,6 @@ def main() -> None:
                          "devices — on CPU set XLA_FLAGS=--xla_force_host_"
                          "platform_device_count=N before launching")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--layers-mode", choices=["auto", "loop", "scan"],
-                    default="auto",
-                    help="compiled layer lowering: 'scan' traces the "
-                         "repeating layer pattern once (HLO O(period), fast "
-                         "compiles on deep configs; needs a periodic sync "
-                         "schedule), 'loop' traces every layer; 'auto' "
-                         "picks scan whenever the schedule allows it")
     args = ap.parse_args()
 
     config = build_config(args.arch, args.n_layers)
@@ -256,14 +249,17 @@ def main() -> None:
         from repro.launch.mesh import make_serving_mesh
 
         mesh = make_serving_mesh(args.mesh)
-    layers_mode = None if args.layers_mode == "auto" else args.layers_mode
-    if args.no_compile:
-        layers_mode = "loop"  # the eager reference reads loop-form weights
-    engine = FedAttnEngine(
-        config, fedattn=fed, bucket=args.bucket,
-        layers_mode=layers_mode, mesh=mesh,
-    )
+    engine = FedAttnEngine(config, fedattn=fed, bucket=args.bucket, mesh=mesh)
     engine.init_random_params(args.seed)
+    if args.no_compile and "stacked" in engine.params:
+        # the eager reference reads loop-form weights: unstack the same draw
+        params = dict(engine.params)
+        stacked = params.pop("stacked")
+        n_per = jax.tree.leaves(stacked)[0].shape[0]
+        params["layers"] = [jax.tree.map(lambda x: x[i], layer)
+                            for i in range(n_per) for layer in stacked]
+        params["layers"] += params.pop("remainder")
+        engine.set_params(params)
 
     if args.stream:
         run_stream(engine, config, args)
@@ -298,7 +294,8 @@ def main() -> None:
     print(f"arch={config.name} N={args.participants} H={args.sync_interval} "
           f"schedule={args.schedule} kv_ratio={args.kv_ratio} "
           f"drivers={'jit' if compile_decode else 'eager'} "
-          f"bucket={args.bucket} layers={engine.layers_mode}")
+          f"bucket={args.bucket} scan={engine._plan.period}x"
+          f"{engine._plan.n_periods}")
     print("generated tokens:\n", res.tokens)
     print("mean token logprob:", float(res.logprobs.mean()))
     print(f"decode throughput: {args.n_new * args.batch / dt:,.1f} tok/s "

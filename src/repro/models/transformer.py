@@ -2,15 +2,17 @@
 architecture families (dense GQA / MoE / RWKV6 / Mamba-hybrid / VLM & audio
 backbones), FedAttn-integrated.
 
-Two application modes:
+The forward pass (``apply``) has two modes: ``loop``, a python loop over
+layers (any sync schedule, trace capture, per-layer introspection), and
+``scan``, a ``lax.scan`` over the repeating layer pattern (HLO size
+O(period); the dry-run and full-size lowering).
 
-  * ``loop``  — python loop over layers; supports arbitrary sync schedules,
-    trace capture for error analysis, per-layer introspection. Used by
-    tests, experiments, small models.
-  * ``scan``  — ``lax.scan`` over the repeating layer *pattern* (period);
-    HLO size O(period), so 62-layer full-size configs lower fast. Requires
-    a periodic sync schedule (the pattern's ``sync`` flags). Used by the
-    multi-pod dry-run and full-size lowering.
+The decode path has one compiled lowering: ``apply_layers_decode_scan``
+scans a :class:`ScanPlan`'s layer unit over stacked params and stacked
+caches. Every sync schedule has a plan — the whole body is a one-period
+plan when nothing shorter repeats. ``decode_step`` without a plan runs
+the per-layer python loop over loop-form params and a per-layer cache
+list: the eager reference that the compiled path is tested against.
 
 Parameters are plain pytrees (dict of dicts / lists); ``stack_params``
 converts loop-form params to scan-form.
@@ -186,21 +188,22 @@ def init_paged_layer_cache(
 
 def init_paged_cache(
     config: ModelConfig, batch: int, num_pages: int, page_size: int,
-    *, plan: Optional["ScanPlan"] = None, kv_quant: Optional[str] = None,
+    *, plan: "ScanPlan", kv_quant: Optional[str] = None,
     mass_width: Optional[int] = None,
 ):
-    """Block-paged decode caches, loop or scan form (mirrors init_cache /
-    init_cache_scan; scan form stacks pool leaves to (n_periods, num_pages,
-    page_size, nkv, dh)). The stacked layout is what the scheduler, the
-    slot writes and the prefix cache see; inside the decode step's layer
-    scan the pool rides the carry as one flat pool of ``n_periods *
-    num_pages`` pages, period ``i`` addressing its own pages at offset
-    ``i * num_pages`` (apply_layers_decode_scan — except under a serving
-    mesh, whose page-sharded pool stays a scanned input). ``kv_quant``
-    selects a quantized pool codec
-    (attention-only stacks; see init_paged_layer_cache); ``mass_width``
-    adds the per-slot attention-mass accumulator leaf (the 'attnmass'
-    decode-stats feed, ibid.)."""
+    """Block-paged decode caches in the plan's stacked form (as
+    init_cache_scan): ``stacked`` pool leaves are (n_periods, num_pages,
+    page_size, nkv, dh), ``remainder`` a list for the trailing layers.
+    The stacked layout is what the scheduler, the slot writes and the
+    prefix cache see; inside the decode step's layer scan the pool rides
+    the carry as one flat pool of ``n_periods * num_pages`` pages, period
+    ``i`` addressing its own pages at offset ``i * num_pages``
+    (apply_layers_decode_scan — except under a serving mesh, whose
+    page-sharded pool stays a scanned input). ``kv_quant`` selects a
+    quantized pool codec (attention-only stacks; see
+    init_paged_layer_cache); ``mass_width`` adds the per-slot
+    attention-mass accumulator leaf (the 'attnmass' decode-stats feed,
+    ibid.)."""
     if kv_quant not in (None, "none") and any(
         s.kind != "attn" for s in config.layer_specs()
     ):
@@ -216,16 +219,14 @@ def init_paged_cache(
     mk = lambda s: init_paged_layer_cache(
         s, config, batch, num_pages, page_size, dt, kv_quant, mass_width
     )
-    if plan is not None:
-        per = [mk(s) for s in plan.specs]
-        stacked = jax.tree.map(
-            lambda x: jnp.zeros((plan.n_periods,) + x.shape, x.dtype), per
-        )
-        return {
-            "stacked": stacked,
-            "remainder": [mk(s) for s in plan.remainder_specs],
-        }
-    return [mk(s) for s in config.layer_specs()]
+    per = [mk(s) for s in plan.specs]
+    stacked = jax.tree.map(
+        lambda x: jnp.zeros((plan.n_periods,) + x.shape, x.dtype), per
+    )
+    return {
+        "stacked": stacked,
+        "remainder": [mk(s) for s in plan.remainder_specs],
+    }
 
 
 def _gather_pool(pool, pages, scales=None):
@@ -297,27 +298,21 @@ def gather_paged_cache(cache, pages):
     k/v; recurrent leaves come back as fresh zero state for B rows (the
     suffix-prefill consumer is attn-only — enforced by the scheduler)."""
     B = pages.shape[0]
-    scan_form = isinstance(cache, dict)
 
-    def rec(x):
-        if scan_form:
-            return jnp.zeros((x.shape[0], B) + x.shape[2:], x.dtype)
-        return jnp.zeros((B,) + x.shape[1:], x.dtype)
-
-    def layer(c):
+    def layer(c, lead):
         if "pk" in c:
             # quantized pools ("sk" present) dequantize inside the gather —
             # the dense transient is f32 regardless of the pool codec
             return {"k": _gather_pool(c["pk"], pages, c.get("sk")),
                     "v": _gather_pool(c["pv"], pages, c.get("sv"))}
-        return {key: rec(val) for key, val in c.items()}
+        return {key: jnp.zeros(x.shape[:lead] + (B,) + x.shape[lead + 1:],
+                               x.dtype)
+                for key, x in c.items()}
 
-    if scan_form:
-        return {
-            "stacked": [layer(c) for c in cache["stacked"]],
-            "remainder": [layer(c) for c in cache["remainder"]],
-        }
-    return [layer(c) for c in cache]
+    return {
+        "stacked": [layer(c, 1) for c in cache["stacked"]],
+        "remainder": [layer(c, 0) for c in cache["remainder"]],
+    }
 
 
 def paged_slot_write(cache, batch, dst_pages, slots):
@@ -325,9 +320,9 @@ def paged_slot_write(cache, batch, dst_pages, slots):
     pool: attention leaves scatter page blocks at ``dst_pages`` ((B, P')
     int32, sentinel entries drop), recurrent leaves write rows at
     ``slots`` ((B,) int32, out-of-bounds padding rows drop)."""
-    scan_form = isinstance(cache, dict)
 
-    def layer(pc, bc):
+    def layer(pc, bc, lead):  # lead: the batch axis (1 on stacked layers)
+        rows = (slice(None),) * lead + (slots,)
         if "pk" in pc:
             if "sk" in pc:
                 pk, sk = _scatter_pool_quant(pc["pk"], pc["sk"], bc["k"],
@@ -342,27 +337,19 @@ def paged_slot_write(cache, batch, dst_pages, slots):
                 # admitted slots restart with zero accumulated mass (the
                 # previous resident's stats must not rank the new pages);
                 # out-of-bounds padding rows drop like every slot write
-                if scan_form:
-                    out["am"] = pc["am"].at[:, slots].set(0.0, mode="drop")
-                else:
-                    out["am"] = pc["am"].at[slots].set(0.0, mode="drop")
+                out["am"] = pc["am"].at[rows].set(0.0, mode="drop")
             return out
-        if scan_form:
-            return {k: pc[k].at[:, slots].set(bc[k].astype(pc[k].dtype))
-                    for k in pc}
-        return {k: pc[k].at[slots].set(bc[k].astype(pc[k].dtype)) for k in pc}
+        return {k: pc[k].at[rows].set(bc[k].astype(pc[k].dtype)) for k in pc}
 
-    if scan_form:
-        return {
-            "stacked": [
-                layer(p, b) for p, b in zip(cache["stacked"], batch["stacked"])
-            ],
-            "remainder": [
-                layer(p, b)
-                for p, b in zip(cache["remainder"], batch["remainder"])
-            ],
-        }
-    return [layer(p, b) for p, b in zip(cache, batch)]
+    return {
+        "stacked": [
+            layer(p, b, 1) for p, b in zip(cache["stacked"], batch["stacked"])
+        ],
+        "remainder": [
+            layer(p, b, 0)
+            for p, b in zip(cache["remainder"], batch["remainder"])
+        ],
+    }
 
 
 def apply_layer_decode(
@@ -482,7 +469,8 @@ def apply_layer_decode(
 
 @dataclass(frozen=True)
 class ScanPlan:
-    """Static description of a scan-over-layers lowering of the decode path.
+    """Static description of the scan-over-layers lowering of the decode
+    path — the only compiled one.
 
     The model body is viewed as ``n_periods`` repetitions of a ``period``-
     layer unit whose sync flags (from the SyncSchedule) are identical in
@@ -490,7 +478,8 @@ class ScanPlan:
     loop. ``period`` is a multiple of the architecture's pattern period but
     may be larger — e.g. a homogeneous (period-1) stack with sync every H-th
     layer scans over an H-layer unit. Traced HLO is O(period), not
-    O(n_layers), so deep configs compile in near-constant time.
+    O(n_layers), so deep configs compile in near-constant time; a schedule
+    that does not repeat gets the whole body as its one period.
     """
 
     period: int
@@ -505,27 +494,28 @@ class ScanPlan:
         return sum(self.sync)
 
     @staticmethod
-    def from_schedule(config: ModelConfig, schedule) -> Optional["ScanPlan"]:
-        """Smallest valid plan for ``schedule``, or None when the schedule is
-        not periodic over the pattern body (scan lowering inapplicable)."""
+    def from_schedule(config: ModelConfig, schedule) -> "ScanPlan":
+        """The plan with the smallest period — a multiple of the pattern
+        period that divides the body and over which ``schedule``'s flags
+        repeat at least twice — or, when no such period exists, the whole
+        body as one period (``n_periods == 1``)."""
         base_p = len(config.pattern)
         n_body = config.n_periods * base_p
         mask = tuple(schedule.mask)
-        specs = config.layer_specs()
-        for p in range(base_p, n_body // 2 + 1, base_p):
-            if n_body % p:
-                continue
-            base = mask[:p]
-            if all(mask[s : s + p] == base for s in range(0, n_body, p)):
-                return ScanPlan(
-                    period=p,
-                    specs=tuple(specs[:p]),
-                    sync=base,
-                    n_periods=n_body // p,
-                    remainder_specs=tuple(config.pattern_remainder),
-                    remainder_sync=tuple(mask[n_body:]),
-                )
-        return None
+        p = next(
+            (p for p in range(base_p, n_body // 2 + 1, base_p)
+             if n_body % p == 0
+             and all(mask[s : s + p] == mask[:p] for s in range(0, n_body, p))),
+            n_body,
+        )
+        return ScanPlan(
+            period=p,
+            specs=tuple(config.layer_specs()[:p]),
+            sync=mask[:p],
+            n_periods=n_body // p,
+            remainder_specs=tuple(config.pattern_remainder),
+            remainder_sync=tuple(mask[n_body:]),
+        )
 
 
 def init_cache_scan(
@@ -547,12 +537,13 @@ def init_cache_scan(
 
 
 def cache_pspecs(cache, cache_axes):
-    """PartitionSpec tree for a decode cache (loop or scan form) with the
-    KV length dim sharded over ``cache_axes`` — the sequence-sharded pool
+    """PartitionSpec tree for a stacked-form decode cache with the KV
+    length dim sharded over ``cache_axes`` — the sequence-sharded pool
     layout of the SPMD continuous-batching scheduler. Attention leaves
     ``k``/``v`` are ``(..., B, capacity, nkv, dh)`` (a leading
-    ``(n_periods,)`` dim in scan form): capacity is always axis ``ndim-3``.
-    SSM/RWKV state leaves have no sequence dim and stay replicated."""
+    ``(n_periods,)`` dim on stacked layers): capacity is always axis
+    ``ndim-3``. SSM/RWKV state leaves have no sequence dim and stay
+    replicated."""
     from jax.sharding import PartitionSpec as P
 
     def leaf(path_key, x):
@@ -571,12 +562,10 @@ def cache_pspecs(cache, cache_axes):
     def layer(c):
         return {key: leaf(key, val) for key, val in c.items()}
 
-    if isinstance(cache, dict):  # scan form
-        return {
-            "stacked": [layer(c) for c in cache["stacked"]],
-            "remainder": [layer(c) for c in cache["remainder"]],
-        }
-    return [layer(c) for c in cache]
+    return {
+        "stacked": [layer(c) for c in cache["stacked"]],
+        "remainder": [layer(c) for c in cache["remainder"]],
+    }
 
 
 #: paged-pool leaves of an attention layer's cache (codes, then the
@@ -606,8 +595,8 @@ def apply_layers_decode_scan(
     accumulator), whose updates come back as the stacked outputs — so
     the trace contains each unit's layers exactly once. Per-round
     sparse-exchange rows are sliced per scan step ((n_periods,
-    syncs_per_period, ...) reshape), keeping round ordering identical to
-    the python-loop path. ``contributed`` is rounds-first: ``(rounds,
+    syncs_per_period, ...) reshape), in the round order of the eager
+    reference's per-layer loop. ``contributed`` is rounds-first: ``(rounds,
     capacity)`` shared rows or ``(rounds, B, capacity)`` per-row rows
     (coalesced multi-request admission — each batch row carries its own
     request's exchange mask).
@@ -832,8 +821,9 @@ class TransformerLM:
     def init_cache(
         self, batch: int, capacity: int, *, plan: Optional[ScanPlan] = None
     ):
-        """Decode caches: a per-layer list (loop mode), or — given a
-        :class:`ScanPlan` — the stacked scan form (see init_cache_scan)."""
+        """Decode caches: given a :class:`ScanPlan`, the stacked form the
+        compiled paths read (see init_cache_scan); without one, the
+        per-layer list of the eager reference."""
         cfg = self.config
         if plan is not None:
             return init_cache_scan(cfg, plan, batch, capacity)
@@ -854,7 +844,6 @@ class TransformerLM:
         backend: Optional[str] = None,
         moe_impl: str = "dense",
         dctx: Optional[FedAttnContext] = None,
-        mode: str = "loop",
         plan: Optional[ScanPlan] = None,
         pages: Optional[jnp.ndarray] = None,
     ):
@@ -875,9 +864,9 @@ class TransformerLM:
         Continuous batching: ``cache_len`` may also be a traced (B,) vector
         — each batch row (KV-pool slot) writes at its own frontier — in
         which case ``dctx`` must carry per-row (B, S_new) positions/segments
-        and (B, capacity) kv_segments (see serving/scheduler.py). Works in
-        both ``loop`` and ``scan`` modes; the vector just rides through
-        apply_layer_decode into the per-row cache scatter. Under an SPMD
+        and (B, capacity) kv_segments (see serving/scheduler.py). The vector
+        just rides through apply_layer_decode into the per-row cache
+        scatter. Under an SPMD
         runtime the same step runs against a capacity-sharded cache
         (:func:`cache_pspecs` gives the layout) — attention layers switch
         to the flash-decoding shard_map path, everything else is unchanged.
@@ -891,26 +880,26 @@ class TransformerLM:
         rows ``< i`` of the same block while causality hides the rows
         ``> i``; rejected rows need no cleanup — the caller's next verify
         block starts at the accepted frontier and overwrites them before
-        any later query can reach them. Holds identically in ``loop`` and
-        ``scan`` modes, dense and paged caches, single-device and SPMD
-        (serving/scheduler.py ``_verify_fn`` is the canonical caller).
+        any later query can reach them. Holds identically for dense and
+        paged caches, single-device and SPMD (serving/scheduler.py
+        ``_verify_fn`` is the canonical caller).
 
-        mode='scan' scans over the layer pattern instead of tracing every
-        layer: requires a :class:`ScanPlan` (periodic sync schedule), params
-        in scan form (``stack_params``) and the cache from
-        ``init_cache(..., plan=plan)``. Traced HLO is O(plan.period)."""
+        Given a :class:`ScanPlan`, the layers lower as one scan over the
+        plan's unit (:func:`apply_layers_decode_scan`): params in scan form
+        (``stack_params``), the cache from ``init_cache(..., plan=plan)``
+        or ``init_paged_cache``; traced HLO is O(plan.period). Without a
+        plan the step runs the eager reference's per-layer loop over
+        loop-form params and a per-layer cache list."""
         cfg = self.config
         x = L.embed_tokens(params["embed"], tokens, cfg)
         if dctx is None:
             dctx = ctx.for_decode_step(_cache_capacity(cache), step)
-        if mode == "scan":
-            if plan is None:
-                raise ValueError("decode_step(mode='scan') requires a ScanPlan")
+        if plan is not None:
             x, new_cache = apply_layers_decode_scan(
                 params, cache, x, cache_len, dctx, cfg, plan,
                 backend=backend, moe_impl=moe_impl, pages=pages,
             )
-        elif mode == "loop":
+        else:
             new_cache = []
             for m, (p, spec) in enumerate(zip(params["layers"], cfg.layer_specs())):
                 x, c = apply_layer_decode(
@@ -918,8 +907,6 @@ class TransformerLM:
                     backend=backend, moe_impl=moe_impl, pages=pages,
                 )
                 new_cache.append(c)
-        else:
-            raise ValueError(f"unknown decode mode {mode!r}")
         with jax.named_scope(scopes.HEAD_SAMPLE):
             x = L.apply_norm(params["final_norm"], x, cfg)
             logits = L.apply_lm_head(params["head"], params["embed"], x, cfg)

@@ -40,16 +40,15 @@ Compiled-serving architecture
   one: up to ~2x padded work at the top of a bucket (both the padded
   prefill and the discarded decode tail) in exchange for executable reuse
   — ``bucket='none'`` opts out per engine.
-* **Scan-over-layers** — when the sync schedule is periodic over the layer
-  body (``ScanPlan.from_schedule``), prefill and decode lower as one
-  ``lax.scan`` over the repeating layer unit with stacked params and
-  stacked per-period KV caches: traced HLO is O(period), not O(n_layers),
-  so deep configs compile in near-constant time. ``layers_mode`` forces
-  'loop'/'scan'; the default picks scan whenever the plan applies. Note
-  the stacked params are a second resident copy of the weights (the
-  loop-form copy backs the eager reference path) — fine at reduced scale;
-  full-size serving should init directly in scan form
-  (``transformer.init_stacked``) and force ``layers_mode='scan'``.
+* **Scan-over-layers** — every compiled entry point lowers the layers as
+  one ``lax.scan`` over the unit of the schedule's ``ScanPlan`` with
+  stacked params and stacked per-period KV caches: traced HLO is
+  O(period), not O(n_layers), so deep configs compile in near-constant
+  time (a schedule that does not repeat scans its whole body once).
+  Loop-form params are restacked once, which keeps a second resident copy
+  of the weights (the loop-form copy backs the eager reference) — fine at
+  reduced scale; full-size serving installs scan-form weights
+  (``transformer.init_stacked``, :meth:`FedAttnEngine.init_random_params`).
 * **Executable caches** — ``_prefill_fns`` / ``_decode_fns`` are keyed on
   the bucketed shapes only (never on partition content); the real length
   enters the decode driver as a traced scalar. ``compile_counts`` exposes
@@ -143,7 +142,6 @@ class FedAttnEngine:
         fedattn: Optional[FedAttnConfig] = None,
         backend: Optional[str] = None,
         bucket: str = "pow2",
-        layers_mode: Optional[str] = None,
         mesh=None,
         kv_quant: Optional[str] = None,
     ):
@@ -152,7 +150,7 @@ class FedAttnEngine:
 
         bucket: 'pow2' pads L/n_new to power-of-two buckets so mixed
         request lengths share compiled executables; 'none' compiles per
-        exact shape. layers_mode: None (auto), 'loop', or 'scan'.
+        exact shape.
 
         kv_quant: 'int8' / 'fp8' turns on the quantized KV representation
         (serving/quant.py): the scheduler's paged pool stores codes +
@@ -198,14 +196,6 @@ class FedAttnEngine:
             )
         self._schedule = self._build_schedule()
         self._plan = T.ScanPlan.from_schedule(config, self._schedule)
-        if layers_mode not in (None, "loop", "scan"):
-            raise ValueError(f"unknown layers_mode {layers_mode!r}")
-        if layers_mode == "scan" and self._plan is None:
-            raise ValueError(
-                "layers_mode='scan' requires a sync schedule periodic over "
-                "the layer body (ScanPlan.from_schedule returned None)"
-            )
-        self.layers_mode = layers_mode or ("scan" if self._plan else "loop")
         # bucketing L pads the *prefill* with segment -1 tokens: attention
         # masks them out of visibility, recurrences treat them as identity
         # state updates (the validity contract, models/ssm) — so every
@@ -213,7 +203,7 @@ class FedAttnEngine:
         # run after the kept tokens and are discarded)
         self._bucket_L_ok = self.fed.causal
         self.params = None
-        self._scan_params = None  # lazily stacked params for scan mode
+        self._scan_params = None  # lazily stacked params of the compiled paths
         if params is not None:
             self.set_params(params)
         # compiled drivers, keyed by bucketed shapes + sampling mode only;
@@ -241,10 +231,11 @@ class FedAttnEngine:
         """Install random weights drawn from ``seed`` by one jitted init,
         in the layout the compiled drivers consume: scan form
         (``transformer.init_stacked`` — one resident copy, nothing to
-        restack) when the layers lower as a scan over the pattern period,
-        loop form otherwise. Scan-form weights serve the compiled paths
-        only (``generate(compile=False)`` needs loop form)."""
-        if self.layers_mode == "scan" and self._plan.period == len(self.config.pattern):
+        restack) when the plan's unit is the pattern period, loop form
+        otherwise (restacked once by :meth:`_run_params`). Scan-form
+        weights serve the compiled paths only (``generate(compile=False)``
+        needs loop form)."""
+        if self._plan.period == len(self.config.pattern):
             init = functools.partial(T.init_stacked, self.model)
         else:
             init = self.model.init
@@ -311,12 +302,11 @@ class FedAttnEngine:
 
     def decode_trace_size(self, B: int, L: int, n_new: int, *, sampled: bool = False) -> int:
         """Length of the decode driver's pretty-printed jaxpr — a proxy for
-        traced-HLO size. O(period) in scan mode (depth-independent),
-        O(n_layers) in loop mode; tests/benchmarks pin the scaling."""
+        traced-HLO size. O(plan.period): depth-independent for a periodic
+        schedule; tests/benchmarks pin the scaling."""
         Lp, Nb = self.bucket_len(L), self._bucket_new(n_new)
         capacity = Lp + Nb
-        plan = self._plan if self.layers_mode == "scan" else None
-        cache = self.model.init_cache(B, capacity, plan=plan)
+        cache = self.model.init_cache(B, capacity, plan=self._plan)
         fn = self._decode_fn(B, capacity, Nb, sampled)
         d0 = self.build_context(L).decode_template(capacity)
         jaxpr = jax.make_jaxpr(fn)(
@@ -327,9 +317,8 @@ class FedAttnEngine:
         return len(str(jaxpr))
 
     def _run_params(self):
-        """Params in the layout the compiled drivers consume."""
-        if self.layers_mode != "scan":
-            return self.params
+        """Params in the layout the compiled drivers consume: scan form,
+        stacked by the plan's period."""
         if self._scan_params is None:
             if "stacked" in self.params:
                 # already scan-form (init_stacked) — no second weight copy,
@@ -354,9 +343,6 @@ class FedAttnEngine:
         if self.bucket == "pow2" and self._bucket_L_ok:
             return _next_pow2(L)
         return L
-
-    # the benchmark's warm-up (bench/harness.warm_up) still asks this name
-    _bucket_len = bucket_len
 
     def _bucket_new(self, n_new: int) -> int:
         if self.bucket == "pow2":
@@ -384,8 +370,7 @@ class FedAttnEngine:
             Lp = self.bucket_len(L)
             Nb = self._bucket_new(n_new)
             capacity = Lp + Nb
-            plan = self._plan if self.layers_mode == "scan" else None
-            cache = self.model.init_cache(B, capacity, plan=plan)
+            cache = self.model.init_cache(B, capacity, plan=self._plan)
             last, cache = self._prefill_compiled(
                 tokens, ctx, cache, extra_embeds, L, Lp, capacity
             )
@@ -468,9 +453,10 @@ class FedAttnEngine:
     # -- prefill ------------------------------------------------------------------
 
     def _round_of(self, layer: int) -> int:
-        """Communication-round index of the sync at ``layer`` — the single
-        numbering both the eager and the compiled prefill use (mirrors
-        FedAttnContext._round_of_layer on the engine's schedule)."""
+        """Communication-round index of the sync at ``layer`` in the eager
+        prefill (mirrors FedAttnContext._round_of_layer on the engine's
+        schedule; the compiled prefill takes the rounds in the same order
+        from the scan's per-period slices)."""
         return sum(1 for m in range(layer) if self._schedule.mask[m])
 
     def _layer_contrib(self, ctx: FedAttnContext, layer: int, capacity: int):
@@ -487,7 +473,7 @@ class FedAttnEngine:
         S_new=L reproduces prefill attention exactly (identical visibility
         masks, including the per-round sparse-exchange rows)."""
         B, L = tokens.shape
-        capacity = _capacity(cache)
+        capacity = T._cache_capacity(cache)
         dctx = ctx.for_decode_step(capacity, 0, n_new=L)
         dctx = dataclasses.replace(
             dctx,
@@ -534,8 +520,8 @@ class FedAttnEngine:
                     per_row: bool = False):
         """Build (or fetch) the jitted bucketed prefill.
 
-        The closure bakes in engine-invariant state only (config, schedule,
-        layers mode); tokens, the real length, position/segment vectors and
+        The closure bakes in engine-invariant state only (config, scan
+        plan); tokens, the real length, position/segment vectors and
         contribution masks are traced arguments — any request in the same
         (B, Lp, capacity) bucket reuses the executable.
 
@@ -551,10 +537,8 @@ class FedAttnEngine:
             return fn
 
         model, backend, cfg = self.model, self.backend, self.config
-        schedule, plan = self._schedule, self._plan
-        scan = self.layers_mode == "scan"
+        plan = self._plan
         proto = self._proto_ctx(capacity)
-        round_of = [self._round_of(m) for m in range(cfg.n_layers)]
 
         def prefill(params, cache, tokens, real_len, q_pos, q_seg, kv_pos,
                     kv_seg, contributed, extra):
@@ -566,20 +550,10 @@ class FedAttnEngine:
                 kv_positions=kv_pos, kv_segments=kv_seg, contributed=None,
             )
             x = model._embed(params, tokens, extra)
-            if scan:
-                x, cache = T.apply_layers_decode_scan(
-                    params, cache, x, 0, dctx, cfg, plan,
-                    backend=backend, contributed=contributed,
-                )
-            else:
-                for m, (p, spec) in enumerate(zip(params["layers"], cfg.layer_specs())):
-                    row = None
-                    if contributed is not None and schedule.is_sync(m):
-                        row = contributed[round_of[m] % n_rounds]
-                    x, cache[m] = T.apply_layer_decode(
-                        p, cache[m], x, 0, dctx, m, spec, cfg,
-                        backend=backend, contributed=row,
-                    )
+            x, cache = T.apply_layers_decode_scan(
+                params, cache, x, 0, dctx, cfg, plan,
+                backend=backend, contributed=contributed,
+            )
             # LM head on the last real position only (L may be < Lp)
             with jax.named_scope(scopes.HEAD_SAMPLE):
                 if per_row:
@@ -618,10 +592,8 @@ class FedAttnEngine:
             return fn
 
         model, backend, cfg = self.model, self.backend, self.config
-        schedule, plan = self._schedule, self._plan
-        scan = self.layers_mode == "scan"
+        plan = self._plan
         proto = self._proto_ctx(capacity)
-        round_of = [self._round_of(m) for m in range(cfg.n_layers)]
 
         def suffix_prefill(params, pool, src_pages, tokens, real_len, write_lo,
                            q_seg, kv_pos, kv_seg, contributed):
@@ -634,20 +606,10 @@ class FedAttnEngine:
                 kv_positions=kv_pos, kv_segments=kv_seg, contributed=None,
             )
             x = model._embed(params, tokens, None)
-            if scan:
-                x, cache = T.apply_layers_decode_scan(
-                    params, cache, x, write_lo, dctx, cfg, plan,
-                    backend=backend, contributed=contributed,
-                )
-            else:
-                for m, (p, spec) in enumerate(zip(params["layers"], cfg.layer_specs())):
-                    row = None
-                    if contributed is not None and schedule.is_sync(m):
-                        row = contributed[round_of[m] % n_rounds]
-                    x, cache[m] = T.apply_layer_decode(
-                        p, cache[m], x, write_lo, dctx, m, spec, cfg,
-                        backend=backend, contributed=row,
-                    )
+            x, cache = T.apply_layers_decode_scan(
+                params, cache, x, write_lo, dctx, cfg, plan,
+                backend=backend, contributed=contributed,
+            )
             with jax.named_scope(scopes.HEAD_SAMPLE):
                 x = jnp.take_along_axis(x, (real_len - 1)[:, None, None], axis=1)
                 x = LY.apply_norm(params["final_norm"], x, cfg)
@@ -665,7 +627,7 @@ class FedAttnEngine:
         """Build (or fetch) the jitted multi-token decode driver.
 
         The closure only bakes in engine-invariant state (model config,
-        sync schedule, layers mode, backend) plus the static key (bucketed
+        scan plan, backend) plus the static key (bucketed
         shapes, sampling mode). Everything that varies call-to-call —
         params, cache, first token, the real prefill length, rng,
         temperature, and the decode-context vectors derived from the
@@ -677,8 +639,7 @@ class FedAttnEngine:
         if fn is not None:
             return fn
 
-        model, backend = self.model, self.backend
-        mode, plan = self.layers_mode, self._plan
+        model, backend, plan = self.model, self.backend, self._plan
         # Proto context: engine-fixed config/schedule objects; array fields
         # are all overridden below.
         proto = self._proto_ctx(capacity)
@@ -695,8 +656,7 @@ class FedAttnEngine:
                 dctx = dataclasses.replace(tpl, positions=q_pos0 + step)
                 logits, cache = model.decode_step(
                     params, cache, tok[:, None], real_len + step, tpl,
-                    step=step, backend=backend, dctx=dctx, mode=mode,
-                    plan=plan,
+                    step=step, backend=backend, dctx=dctx, plan=plan,
                 )
                 with jax.named_scope(scopes.HEAD_SAMPLE):
                     nxt_logits = logits[:, -1]
@@ -796,9 +756,3 @@ def _verify_candidates(logits, draft, temps, keys, fold, sampled):
     lps = jnp.take_along_axis(lp, cand[..., None], axis=-1)[..., 0]
     return cand, lps, accept
 
-
-def _capacity(cache) -> int:
-    for c in cache:
-        if "k" in c:
-            return c["k"].shape[1]
-    return 1

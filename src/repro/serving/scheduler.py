@@ -161,8 +161,8 @@ class ContinuousBatchingScheduler:
     """Admit → step → retire loop over a fixed slot pool.
 
     Args:
-      engine: a FedAttnEngine (its compiled prefill, bucket policy,
-        layers_mode and — when present — serving mesh are reused as-is).
+      engine: a FedAttnEngine (its compiled prefill, bucket policy, scan
+        plan and — when present — serving mesh are reused as-is).
       max_slots: pool rows = maximum concurrently-decoding requests.
       capacity: KV pages per slot. Every admitted request needs
         ``bucketed_prefill_len <= capacity`` and ``L + n_new <= capacity``.
@@ -270,7 +270,7 @@ class ContinuousBatchingScheduler:
         self.steps_per_admit = steps_per_admit
         self.page_size = page_size
         self._paged = kv_layout == "paged"
-        self._plan = engine._plan if engine.layers_mode == "scan" else None
+        self._plan = engine._plan
         self._spmd = getattr(engine, "spmd", None)
         n_shards = (
             self._spmd.mesh.shape[self._spmd.cache_axes[0]]
@@ -961,8 +961,6 @@ class ContinuousBatchingScheduler:
         if self._write_fn is not None:
             return self._write_fn
 
-        scan_form = isinstance(self.cache, dict)
-
         if self._paged:
             # paged layout: attention KV routes through per-row destination
             # page tables (sentinel entries — padding rows, shared
@@ -980,23 +978,18 @@ class ContinuousBatchingScheduler:
             return self._write_fn
 
         def slot_write(pool, batch, slots):
-            if scan_form:
-                # stacked leaves: (n_periods, B, ...) — batch axis 1
-                stacked = jax.tree.map(
-                    lambda pl, ol: pl.at[:, slots].set(ol.astype(pl.dtype)),
-                    pool["stacked"], batch["stacked"],
-                )
-                remainder = jax.tree.map(
-                    lambda pl, ol: pl.at[slots].set(ol.astype(pl.dtype)),
-                    pool["remainder"], batch["remainder"],
-                )
-                out = {"stacked": stacked, "remainder": remainder}
-            else:
-                out = jax.tree.map(
-                    lambda pl, ol: pl.at[slots].set(ol.astype(pl.dtype)),
-                    pool, batch,
-                )
-            return self._constrain_cache(out)
+            # stacked leaves: (n_periods, B, ...) — batch axis 1
+            stacked = jax.tree.map(
+                lambda pl, ol: pl.at[:, slots].set(ol.astype(pl.dtype)),
+                pool["stacked"], batch["stacked"],
+            )
+            remainder = jax.tree.map(
+                lambda pl, ol: pl.at[slots].set(ol.astype(pl.dtype)),
+                pool["remainder"], batch["remainder"],
+            )
+            return self._constrain_cache(
+                {"stacked": stacked, "remainder": remainder}
+            )
 
         self._trace_guards["slot_write"].charge(())
         self._write_fn = jax.jit(
@@ -1036,8 +1029,7 @@ class ContinuousBatchingScheduler:
             return fn
 
         eng = self.engine
-        model, backend = eng.model, eng.backend
-        mode, plan = eng.layers_mode, eng._plan
+        model, backend, plan = eng.model, eng.backend, self._plan
         proto = self._decode_proto()
         kv_pos = jnp.arange(self._cap, dtype=jnp.int32)
 
@@ -1055,8 +1047,7 @@ class ContinuousBatchingScheduler:
                 )
                 logits, cache = model.decode_step(
                     params, cache, tok[:, None], wp, proto,
-                    backend=backend, dctx=dctx, mode=mode, plan=plan,
-                    pages=pages,
+                    backend=backend, dctx=dctx, plan=plan, pages=pages,
                 )
                 with jax.named_scope(scopes.HEAD_SAMPLE):
                     last = logits[:, -1]
@@ -1100,8 +1091,7 @@ class ContinuousBatchingScheduler:
             return self._verify
 
         eng = self.engine
-        model, backend = eng.model, eng.backend
-        mode, plan = eng.layers_mode, eng._plan
+        model, backend, plan = eng.model, eng.backend, self._plan
         proto = self._decode_proto()
         kv_pos = jnp.arange(self._cap, dtype=jnp.int32)
         offs = jnp.arange(self.spec_k + 1, dtype=jnp.int32)
@@ -1120,8 +1110,7 @@ class ContinuousBatchingScheduler:
             )
             logits, cache = model.decode_step(
                 params, cache, inp, write_pos, proto,
-                backend=backend, dctx=dctx, mode=mode, plan=plan,
-                pages=pages,
+                backend=backend, dctx=dctx, plan=plan, pages=pages,
             )  # (S, k+1, V) — every position's logits, not just the last
             with jax.named_scope(scopes.HEAD_SAMPLE):
                 cand, lps, accept = _verify_candidates(

@@ -454,21 +454,26 @@ def test_audit_traces_without_compiling():
 
 def test_trace_scaling_is_O_period():
     """Generalized O(period) contract: doubling scan depth keeps every
-    entry point's trace flat; the loop lowering is (correctly) reported as
-    out of scope."""
+    entry point's trace flat; a schedule that does not repeat scans its
+    whole body as one period, whose trace grows with depth, and the audit
+    reports it."""
 
-    def make(mode):
+    def make(periodic):
         def build(k):
-            cfg = tiny_config(
-                n_layers=2 * k,
-                pattern=(LayerSpec(), LayerSpec(sync=True)),
-                fedattn=FedAttnConfig(n_participants=4, sync_interval=2),
-            )
+            if periodic:
+                pattern = (LayerSpec(), LayerSpec(sync=True))
+                fed = FedAttnConfig(n_participants=4, sync_interval=2)
+            else:  # one sync, at the last layer: one period of 2k layers
+                pattern = (LayerSpec(),)
+                fed = FedAttnConfig(n_participants=4, sync_interval=2 * k)
+            cfg = tiny_config(n_layers=2 * k, pattern=pattern, fedattn=fed)
             params = build_model(cfg).init(jax.random.key(0))
-            return FedAttnEngine(cfg, params, layers_mode=mode)
+            eng = FedAttnEngine(cfg, params)
+            assert eng._plan.n_periods == (k if periodic else 1)
+            return eng
 
         return build
 
-    assert jaxpr_audit.audit_trace_scaling(make("scan"), depths=(2, 4)) == []
-    issues = jaxpr_audit.audit_trace_scaling(make("loop"), depths=(2, 4))
+    assert jaxpr_audit.audit_trace_scaling(make(True), depths=(2, 4)) == []
+    issues = jaxpr_audit.audit_trace_scaling(make(False), depths=(2, 4))
     assert issues and issues[0].check == "scaling"

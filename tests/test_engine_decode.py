@@ -172,7 +172,7 @@ def _deep_cfg(**fed_kw):
 @pytest.mark.parametrize("regime", ["plain", "sparse_kv", "windowed"])
 def test_scan_vs_loop_decode_parity(regime):
     """Scan-over-layers (stacked params + stacked per-period KV caches) must
-    match the loop lowering and the eager reference across schedules."""
+    match the eager reference's per-layer loop across schedules."""
     from repro.models import build_model
 
     if regime == "sparse_kv":
@@ -186,16 +186,13 @@ def test_scan_vs_loop_decode_parity(regime):
     else:
         cfg = _deep_cfg()
     params = build_model(cfg).init(jax.random.key(0))
-    eng_scan = FedAttnEngine(cfg, params)  # auto resolves to scan
-    eng_loop = FedAttnEngine(cfg, params, layers_mode="loop")
-    assert eng_scan.layers_mode == "scan"
+    eng = FedAttnEngine(cfg, params)
+    assert eng._plan.n_periods == 4
     toks = _tokens(cfg)
     rng = jax.random.key(7)
-    r_scan = eng_scan.generate(toks, N_NEW, rng=rng)
-    r_loop = eng_loop.generate(toks, N_NEW, rng=rng)
-    r_eager = eng_loop.generate(toks, N_NEW, rng=rng, compile=False)
+    r_scan = eng.generate(toks, N_NEW, rng=rng)
+    r_eager = eng.generate(toks, N_NEW, rng=rng, compile=False)
     np.testing.assert_array_equal(r_scan.tokens, r_eager.tokens)
-    np.testing.assert_array_equal(r_loop.tokens, r_eager.tokens)
     np.testing.assert_allclose(
         r_scan.logprobs, r_eager.logprobs, atol=1e-4, rtol=1e-4
     )
@@ -238,35 +235,38 @@ def test_bucket_none_policy_exact_shapes():
 def test_scan_decode_trace_size_is_O_period():
     """Acceptance: the compiled decode driver for a periodic schedule traces
     the layer pattern once — doubling n_layers must not grow the trace
-    (O(period)), while the loop lowering's trace is O(n_layers)."""
+    (O(period)), while a schedule that does not repeat scans its whole
+    body as one period, whose trace is O(n_layers)."""
     from repro.models import build_model
 
-    def eng_for(n_layers, mode):
-        cfg = tiny_config(
-            n_layers=n_layers,
-            pattern=(LayerSpec(), LayerSpec(sync=True)),
-            fedattn=FedAttnConfig(n_participants=4, sync_interval=2),
-        )
+    def eng_for(n_layers, periodic=True):
+        if periodic:
+            pattern = (LayerSpec(), LayerSpec(sync=True))
+            fed = FedAttnConfig(n_participants=4, sync_interval=2)
+        else:  # one sync, at the last layer: nothing shorter repeats
+            pattern = (LayerSpec(),)
+            fed = FedAttnConfig(n_participants=4, sync_interval=n_layers)
+        cfg = tiny_config(n_layers=n_layers, pattern=pattern, fedattn=fed)
         params = build_model(cfg).init(jax.random.key(0))
-        return FedAttnEngine(cfg, params, layers_mode=mode)
+        return FedAttnEngine(cfg, params)
 
-    s8 = eng_for(8, "scan").decode_trace_size(B, L, N_NEW)
-    s16 = eng_for(16, "scan").decode_trace_size(B, L, N_NEW)
-    l16 = eng_for(16, "loop").decode_trace_size(B, L, N_NEW)
+    s8 = eng_for(8).decode_trace_size(B, L, N_NEW)
+    s16 = eng_for(16).decode_trace_size(B, L, N_NEW)
+    whole = eng_for(16, periodic=False)
+    assert (whole._plan.period, whole._plan.n_periods) == (16, 1)
+    w16 = whole.decode_trace_size(B, L, N_NEW)
     assert s16 < 1.2 * s8, f"scan trace grew with depth: {s8} -> {s16}"
-    assert l16 > 2.0 * s16, f"scan trace not smaller than loop: {s16} vs {l16}"
+    assert w16 > 2.0 * s16, (
+        f"periodic trace not smaller than one period: {s16} vs {w16}")
 
 
-def test_uniform_H_equal_to_depth_has_no_scan_plan():
-    """Pins why BENCH_serving's decode_N4_H4 point runs layers_mode='loop'
-    while every H=2 point scans: on the 4-layer homogeneous bench stack
-    with sync every 4th layer, the smallest schedule-periodic unit IS the
-    whole body — ScanPlan.from_schedule requires >= 2 repetitions (a
-    1-iteration scan has no O(period) trace advantage, only scan overhead)
-    and correctly returns None. Doubling the depth restores scan with the
-    same H=4 schedule (see ROADMAP.md, scan-plan coverage note)."""
+def test_uniform_H_equal_to_depth_scans_one_period():
+    """On the 4-layer homogeneous stack with sync every 4th layer the
+    smallest schedule-periodic unit IS the whole body: the plan is one
+    period of 4 layers, and the compiled paths serving it match the eager
+    reference. Doubling the depth gives the same H=4 schedule two
+    periods."""
     from repro.models import build_model
-    from repro.models.transformer import ScanPlan
 
     def eng_for(n_layers):
         cfg = tiny_config(
@@ -277,12 +277,17 @@ def test_uniform_H_equal_to_depth_has_no_scan_plan():
         return FedAttnEngine(cfg, params)
 
     e4 = eng_for(4)
-    assert e4._plan is None
-    assert e4.layers_mode == "loop"
-    assert ScanPlan.from_schedule(e4.config, e4._schedule) is None
+    assert e4._plan.period == 4 and e4._plan.n_periods == 1
+    toks = _tokens(e4.config)
+    rng = jax.random.key(7)
+    r_scan = e4.generate(toks, N_NEW, rng=rng)
+    r_eager = e4.generate(toks, N_NEW, rng=rng, compile=False)
+    np.testing.assert_array_equal(r_scan.tokens, r_eager.tokens)
+    np.testing.assert_allclose(
+        r_scan.logprobs, r_eager.logprobs, atol=1e-4, rtol=1e-4
+    )
 
     e8 = eng_for(8)  # same H=4 schedule, twice the depth -> 2 repetitions
-    assert e8.layers_mode == "scan"
     assert e8._plan.period == 4 and e8._plan.n_periods == 2
 
 

@@ -415,8 +415,7 @@ def test_jaxpr_audit_fused_decode_clean_and_has_teeth():
     )
     entries = JA.trace_scheduler_entries(sched)
     step = next(e for e in entries if e.name == "scheduler.decode_step")
-    rank = 4 if sched._plan is None else 5
-    hits = JA.pool_gather_issues(step.name, step.traced, min_pool_rank=rank)
+    hits = JA.pool_gather_issues(step.name, step.traced, min_pool_rank=5)
     assert hits and all(i.check == "pool_gather" for i in hits)
 
     # a non-pallas engine is rejected, not silently waved through

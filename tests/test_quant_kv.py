@@ -300,12 +300,13 @@ def test_recurrent_stacks_raise_not_implemented(kind):
     """Recurrent layers carry per-slot STATE, not per-position KV — no
     page/row granularity to attach scales to. The blocker is named, not
     silently ignored."""
+    from repro.configs import schedule_from_config
     from repro.models import transformer as T
 
+    cfg = stack_config(kind)
+    plan = T.ScanPlan.from_schedule(cfg, schedule_from_config(cfg))
     with pytest.raises(NotImplementedError, match="attention-only stack"):
-        T.init_paged_cache(
-            stack_config(kind), 2, 8, 8, kv_quant="int8"
-        )
+        T.init_paged_cache(cfg, 2, 8, 8, plan=plan, kv_quant="int8")
 
 
 def test_kv_quant_config_validation():

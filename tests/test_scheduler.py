@@ -1,6 +1,6 @@
 """Continuous-batching scheduler (serving/scheduler.py): per-request
-token/logprob parity with standalone generate (greedy AND sampled, loop
-AND scan layer lowering, sparse KV exchange, heterogeneous partitions),
+token/logprob parity with standalone generate (greedy AND sampled,
+one-period AND periodic scan plans, sparse KV exchange, heterogeneous partitions),
 the zero-recompile contract (ONE resident decode executable across a
 trace whose active-slot set changes every step), slot reuse, result
 ordering, and capacity validation.
@@ -91,7 +91,7 @@ def test_parity_scan_mode_fused_steps():
         fedattn=FedAttnConfig(n_participants=4, sync_interval=2),
     )
     e = _engine(cfg)
-    assert e.layers_mode == "scan"
+    assert e._plan.n_periods == 4
     reqs = [_req(0, 24, 8, cfg=cfg), _req(1, 12, 5, temp=0.7, cfg=cfg),
             _req(2, 20, 3, cfg=cfg)]
     sched = ContinuousBatchingScheduler(

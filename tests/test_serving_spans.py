@@ -171,7 +171,7 @@ def test_decode_and_prefill_steps_carry_the_model_scopes():
     layers make two periods of the sync schedule, so the layers lower as a
     scan (as in the benchmark's configurations)."""
     eng = _engine(tiny_config(n_layers=8))
-    assert eng.layers_mode == "scan"
+    assert eng._plan.n_periods == 2
     sched = ContinuousBatchingScheduler(eng, max_slots=2, capacity=40, steps_per_admit=1)
     sched.submit(_req(0, 10, 2))
     sched.step()

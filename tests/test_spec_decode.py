@@ -123,7 +123,7 @@ def test_spec_parity_scan_mode():
         fedattn=FedAttnConfig(n_participants=4, sync_interval=2),
     )
     e = _engine(cfg)
-    assert e.layers_mode == "scan"
+    assert e._plan.n_periods == 4
     reqs = [_req(0, 24, 6, cfg=cfg), _req(1, 12, 4, temp=0.7, cfg=cfg)]
     sched = ContinuousBatchingScheduler(e, max_slots=2, capacity=64, spec_k=3)
     res = sched.run(reqs)
@@ -303,11 +303,14 @@ class ScriptedDrafter:
 
 
 def _slot_kv(sched, slot, span):
-    """Logical per-position (K, V) rows of one slot over [0, span), read
-    through the slot's own page table (paged) or row (dense)."""
-    assert isinstance(sched.cache, list)  # loop-form stacks only
+    """Logical per-position (K, V) rows of one slot over [0, span), layer
+    by layer, read through the slot's own page table (paged) or row
+    (dense) of the stacked pool."""
+    c = sched.cache
+    layers = [jax.tree.map(lambda x: x[n], layer)
+              for n in range(sched._plan.n_periods) for layer in c["stacked"]]
     out = []
-    for layer in sched.cache:
+    for layer in layers + list(c["remainder"]):
         if "pk" in layer:
             pk, pv = np.asarray(layer["pk"]), np.asarray(layer["pv"])
             ps = pk.shape[1]
